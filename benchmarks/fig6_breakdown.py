@@ -176,18 +176,21 @@ def _kernel_breakdown(writer, smoke: bool = False):
     r_rows, l = (256, 128) if smoke else (1024, 512)
     s_rows, c_rows = 32, 24
     key = jax.random.PRNGKey(42)
-    q_table = jax.random.randint(key, (r_rows, l), -127, 128, dtype=jnp.int8)
+    # int8 tables in the lane-dense record layout [R, W, 128] (core.compression)
+    w = l // 128
+    q_table = jax.random.randint(key, (r_rows, w, 128), -127, 128, dtype=jnp.int8)
     scales = jax.random.uniform(jax.random.fold_in(key, 1), (r_rows, 1),
                                 minval=1e-3, maxval=2.0)
     rows_s = jax.random.randint(jax.random.fold_in(key, 2), (s_rows,), 0, r_rows)
-    x = jax.random.normal(jax.random.fold_in(key, 3), (c_rows, l))
+    x = jax.random.normal(jax.random.fold_in(key, 3), (c_rows, w, 128))
     rows_c = jax.random.randint(jax.random.fold_in(key, 4), (c_rows,), -1, r_rows)
 
     # --- gather+dequant: two-pass (gather int8 -> full-width dequant) vs fused
     @jax.jit
     def gather_unfused(qt, st, rows):
         idx = jnp.clip(rows, 0, qt.shape[0] - 1)
-        return ops.dequantize(qt[idx], st[idx])
+        q = qt[idx]
+        return ops.dequantize(q.reshape(q.shape[0], -1), st[idx]).reshape(q.shape)
 
     gather_fused = ops.gather_dequant
     g_un_us = _time(gather_unfused, q_table, scales, rows_s, n=n)
@@ -199,9 +202,9 @@ def _kernel_breakdown(writer, smoke: bool = False):
     # --- encode+scatter: two-pass (quantize -> scatter both tables) vs fused
     @jax.jit
     def scatter_unfused(qt, st, xv, rows):
-        q, s = ops.quantize(xv)
+        q, s = ops.quantize(xv.reshape(xv.shape[0], -1))
         safe = jnp.where(rows >= 0, rows, qt.shape[0])
-        return (qt.at[safe].set(q, mode="drop"),
+        return (qt.at[safe].set(q.reshape(xv.shape), mode="drop"),
                 st.at[safe].set(s, mode="drop"))
 
     scatter_fused = ops.encode_scatter

@@ -193,15 +193,19 @@ print("PAYLOAD " + json.dumps(payload))
 def run(writer, smoke: bool = False, json_path: str = "BENCH_fig7.json"):
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
+    # a fake-device CPU figure by design: the child never competes with the
+    # parent for an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = os.path.join(here, "src") + ":" + here
     env["REPRO_FIG7_SMOKE"] = "1" if smoke else "0"
     p = subprocess.run([sys.executable, "-c", CHILD], capture_output=True,
                        text=True, timeout=1800, env=env)
     line = [l for l in p.stdout.splitlines() if l.startswith("PAYLOAD ")]
-    payload = json.loads(line[0][len("PAYLOAD "):]) if line else {}
     if not line:
-        writer.row("fig7/child", "nan", f"FAILED:{p.stderr[-300:]}")
+        raise RuntimeError(f"fig7 child printed no payload (rc={p.returncode}):\n"
+                           f"{p.stderr[-2000:]}")
+    payload = json.loads(line[0][len("PAYLOAD "):])
 
     rows = {}
     # (a) overhead fraction vs worker count

@@ -13,6 +13,7 @@ import sys
 import traceback
 
 from repro.utils.logging import CSVWriter
+from repro.utils.platform import enable_compile_cache
 
 
 def main() -> None:
@@ -23,6 +24,7 @@ def main() -> None:
                     help="shrunk fig5a/fig6 runs for CI (still emit BENCH_*.json)")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    enable_compile_cache()
 
     from benchmarks import (fig5a_buffer_size, fig5b_strategies, fig6_breakdown,
                             fig7_scalability, fig8_serving, roofline_table)

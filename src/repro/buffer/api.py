@@ -45,20 +45,24 @@ def _fused_of(rcfg) -> bool:
     return bool(getattr(rcfg, "fused_kernels", False)) if rcfg is not None else False
 
 
-def buffer_update(state: AnyBufferState, items, labels, key, rcfg) -> AnyBufferState:
-    """Policy-driven Alg-1 push of a candidate mini-batch into either store."""
+def buffer_update(state: AnyBufferState, items, labels, key, rcfg, *,
+                  cold_host: bool = False) -> AnyBufferState:
+    """Policy-driven Alg-1 push of a candidate mini-batch into either store.
+    ``cold_host``: a tiered store's cold records live in host memory."""
     pol = _policy_of(rcfg)
     if isinstance(state, TieredState):
         return tiered_update(state, items, labels, key, rcfg.num_candidates, pol,
-                             fused=_fused_of(rcfg))
+                             fused=_fused_of(rcfg), cold_host=cold_host)
     return local_update(state, items, labels, key, rcfg.num_candidates, pol)
 
 
-def buffer_sample(state: AnyBufferState, key, n: int, rcfg=None):
+def buffer_sample(state: AnyBufferState, key, n: int, rcfg=None, *,
+                  cold_host: bool = False):
     """Draw ``n`` representatives from either store under the configured policy."""
     pol = _policy_of(rcfg)
     if isinstance(state, TieredState):
-        return tiered_sample(state, key, n, pol, fused=_fused_of(rcfg))
+        return tiered_sample(state, key, n, pol, fused=_fused_of(rcfg),
+                             cold_host=cold_host)
     return local_sample(state, key, n, pol)
 
 
@@ -97,11 +101,10 @@ def buffer_obs(state: AnyBufferState, rcfg=None):
 
 def resolve_placement(rcfg, devices=None) -> str:
     """Resolved storage placement of the configured buffer's bulk capacity:
-    ``'device'`` for flat (HBM-only) configs, and for tiered configs whatever
-    ``tiered.resolve_cold_placement`` probes (``'pinned_host'`` where the
-    runtime exposes it, ``'device'`` fallback). Dry-run records and
-    ``BuiltStep.meta`` surface this so a tiered config that silently landed in
-    HBM is visible."""
+    ``'device'`` for flat (HBM-only) configs, and for tiered configs the
+    platform's cold-tier memory (``tiered.resolve_cold_placement``:
+    ``'pinned_host'`` on an accelerator, ``'device'`` on the CPU). Dry-run
+    records and ``BuiltStep.meta`` surface it."""
     from repro.buffer.tiered import resolve_cold_placement
 
     if not getattr(rcfg, "tiered", False):

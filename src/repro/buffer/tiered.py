@@ -8,7 +8,10 @@ buffer caps S_max at HBM size. This store splits each bucket into
   * a **cold tier** — records the hot tier evicts, row-quantized to int8 through
     the existing ``kernels/quantize.py`` + ``core/compression.py`` path (4x byte
     saving) and, on TPU, placed in host memory (``cold_shardings``), so
-    ``slots_per_bucket`` can exceed device memory.
+    ``slots_per_bucket`` can exceed device memory. A host-resident cold tier
+    (``cold_host=True``) is never touched in place by device code: the sampled
+    rows and the demoted rows cross memory kinds one row at a time
+    (``host_gather_rows`` / ``host_scatter_rows``).
 
 Demotion is *asynchronous and batched*, mirroring the PR-1 pipelining discipline
 (DESIGN.md §3/§6): records evicted from the hot tier at step t are parked in a
@@ -37,7 +40,6 @@ from repro.buffer.state import (
     init_buffer,
     local_sample,
     local_sample_rows,
-    local_update,
     local_update_rows,
     local_update_with_evicted,
 )
@@ -107,7 +109,75 @@ def _pack_stage(evicted, labels, valid, stage_rows: int):
     return stage, labels[take], valid[take] & in_range
 
 
-def tiered_flush(state: TieredState, key, *, fused: bool = False) -> TieredState:
+# Smallest record (in elements) the cold tier keeps in host memory. A host row
+# transfer moves whole lane tiles, and the TPU cannot update a sub-tile slice
+# of a host buffer; per-record scalars (labels, task ids, int8 row scales) are
+# a few bytes per record and stay in device memory.
+HOST_MIN_RECORD = 128
+
+
+def host_resident(record_shape) -> bool:
+    """Whether a cold leaf with this per-record shape lives in host memory
+    when the cold tier is host-placed."""
+    n = 1
+    for d in record_shape:
+        n *= d
+    return len(record_shape) > 0 and n >= HOST_MIN_RECORD
+
+
+def _host_space(x):
+    return jax.device_put(x, jax.memory.Space.Host)
+
+
+def _cold_rows_get(leaf, rows, cold_host: bool):
+    if cold_host and host_resident(leaf.shape[2:]):
+        return host_gather_rows(leaf, rows)
+    return leaf.reshape((-1,) + leaf.shape[2:])[rows]
+
+
+def _cold_rows_set(leaf, rows, values, cold_host: bool):
+    if cold_host and host_resident(leaf.shape[2:]):
+        return host_scatter_rows(leaf, rows, values)
+    flat = leaf.reshape((-1,) + leaf.shape[2:])
+    return flat.at[rows].set(values.astype(leaf.dtype), mode="drop").reshape(leaf.shape)
+
+
+def host_gather_rows(leaf, rows):
+    """Copy flat rows ``rows`` (i32[n], in range) of a host-resident
+    ``[K, slots, ...]`` leaf into device memory: one row transfer each, so
+    only the sampled bytes cross to the device. Returns ``[n, ...]``."""
+    flat = _host_space(leaf).reshape((-1,) + leaf.shape[2:])
+    n = rows.shape[0]
+
+    def one(i, out):
+        row = jax.lax.dynamic_index_in_dim(flat, rows[i], keepdims=False)
+        return out.at[i].set(jax.device_put(row, jax.memory.Space.Device))
+
+    return jax.lax.fori_loop(0, n, one, jnp.zeros((n,) + flat.shape[1:], leaf.dtype))
+
+
+def host_scatter_rows(leaf, rows, values):
+    """Write ``values[i]`` into flat row ``rows[i]`` of a host-resident
+    ``[K, slots, ...]`` leaf, one row transfer each, in order (duplicate rows:
+    last write wins, as the XLA scatter). Rows outside ``[0, K*slots)`` are
+    dropped: their target keeps its bytes. Returns the updated leaf."""
+    r = leaf.shape[0] * leaf.shape[1]
+    flat = _host_space(leaf).reshape((r,) + leaf.shape[2:])
+
+    def one(i, flat):
+        keep = (rows[i] >= 0) & (rows[i] < r)
+        idx = jnp.clip(rows[i], 0, r - 1)
+        old = jax.device_put(jax.lax.dynamic_index_in_dim(flat, idx, keepdims=False),
+                             jax.memory.Space.Device)
+        new = jnp.where(keep, values[i].astype(leaf.dtype), old)
+        return jax.lax.dynamic_update_index_in_dim(flat, _host_space(new), idx, 0)
+
+    flat = jax.lax.fori_loop(0, rows.shape[0], one, flat)
+    return flat.reshape(leaf.shape)
+
+
+def tiered_flush(state: TieredState, key, *, fused: bool = False,
+                 cold_host: bool = False) -> TieredState:
     """Flush the pending demotions (staged at step t−1) into the cold archive:
     one batched int8 encode + reservoir insert. Clears ``stage_valid`` so a
     standalone flush (the phase-decomposed form, repro.obs.pipeline) cannot
@@ -119,21 +189,26 @@ def tiered_flush(state: TieredState, key, *, fused: bool = False) -> TieredState
     encoded batch. Row targeting and key use go through the same
     ``local_update_rows`` as the XLA path, so both are bit-identical. The cold
     tier always runs the default reservoir policy (stateless aux), which is
-    what lets the fused form skip the generic ``update_aux`` hook."""
+    what lets the fused form skip the generic ``update_aux`` hook.
+
+    ``cold_host=True`` (the cold records live in host memory): the staged rows
+    are encoded on the device and each lands in its host row through
+    ``host_scatter_rows``. ``fused`` does not apply there: the fused kernels
+    address an HBM table."""
     comp = _compression()
-    if fused:
-        flat, _, _, _, new_counts, new_seen = local_update_rows(
-            state.cold, state.stage_labels, key,
-            num_candidates=state.stage_labels.shape[0],
-            accept_mask=state.stage_valid)
-        new_data = comp.encode_scatter_batch(
-            state.cold.data, state.stage, record_spec_of(state), flat)
-        cold = BufferState(new_data, new_counts, new_seen, state.cold.aux)
+    flat, _, _, _, new_counts, new_seen = local_update_rows(
+        state.cold, state.stage_labels, key,
+        num_candidates=state.stage_labels.shape[0],
+        accept_mask=state.stage_valid)
+    spec = record_spec_of(state)
+    if fused and not cold_host:
+        new_data = comp.encode_scatter_batch(state.cold.data, state.stage,
+                                             spec, flat)
     else:
-        encoded = comp.encode_batch(state.stage, record_spec_of(state))
-        cold = local_update(state.cold, encoded, state.stage_labels, key,
-                            num_candidates=state.stage_labels.shape[0],
-                            accept_mask=state.stage_valid)
+        new_data = jax.tree_util.tree_map(
+            lambda leaf, x: _cold_rows_set(leaf, flat, x, cold_host),
+            state.cold.data, comp.encode_batch(state.stage, spec))
+    cold = BufferState(new_data, new_counts, new_seen, state.cold.aux)
     return state._replace(cold=cold,
                           stage_valid=jnp.zeros_like(state.stage_valid))
 
@@ -153,7 +228,8 @@ def tiered_push(state: TieredState, items, labels, key, num_candidates: int,
 
 
 def tiered_update(state: TieredState, items, labels, key, num_candidates: int,
-                  policy=None, *, fused: bool = False) -> TieredState:
+                  policy=None, *, fused: bool = False,
+                  cold_host: bool = False) -> TieredState:
     """One tiered Alg-1 step: flush last step's staged demotions into the cold tier
     (batched int8 encode — off the critical path), update the hot tier under the
     policy, and stage whatever the hot tier evicted for the next flush.
@@ -163,12 +239,12 @@ def tiered_update(state: TieredState, items, labels, key, num_candidates: int,
     form (the flush touches only ``cold``/``stage_valid``; the push reads
     ``hot`` and replaces the stage wholesale)."""
     k_hot, k_flush = jax.random.split(key)
-    return tiered_push(tiered_flush(state, k_flush, fused=fused), items, labels,
-                       k_hot, num_candidates, policy)
+    flushed = tiered_flush(state, k_flush, fused=fused, cold_host=cold_host)
+    return tiered_push(flushed, items, labels, k_hot, num_candidates, policy)
 
 
 def tiered_sample(state: TieredState, key, n: int, policy=None, *,
-                  fused: bool = False):
+                  fused: bool = False, cold_host: bool = False):
     """Draw ``n`` records across both tiers, tier chosen ∝ fill (unbiased over the
     union); cold rows are dequantized back to the record dtypes. Returns
     (items [n, ...], valid bool[n]).
@@ -177,16 +253,21 @@ def tiered_sample(state: TieredState, key, n: int, policy=None, *,
     kernel (``compression.decode_gather_batch``): int8 rows dequantize in VMEM
     on the way out instead of materialising a full-width gathered batch first.
     Row selection shares ``local_sample_rows`` with the XLA path — same key
-    use, same rows, bit-identical output."""
+    use, same rows, bit-identical output.
+
+    ``cold_host=True``: the same rows are copied out of host memory one by one
+    (``host_gather_rows``) and decoded on the device; ``fused`` does not
+    apply there."""
     comp = _compression()
     k_hot, k_cold, k_mix = jax.random.split(key, 3)
     hot_items, hot_valid = local_sample(state.hot, k_hot, n, policy)
-    if fused:
-        cold_rows, cold_valid = local_sample_rows(state.cold, k_cold, n)
+    cold_rows, cold_valid = local_sample_rows(state.cold, k_cold, n)
+    if fused and not cold_host:
         cold_items = comp.decode_gather_batch(
             state.cold.data, record_spec_of(state), cold_rows)
     else:
-        cold_stored, cold_valid = local_sample(state.cold, k_cold, n)
+        cold_stored = jax.tree_util.tree_map(
+            lambda leaf: _cold_rows_get(leaf, cold_rows, cold_host), state.cold.data)
         cold_items = comp.decode_batch(cold_stored, record_spec_of(state))
 
     hot_total = jnp.sum(state.hot.counts)
@@ -239,11 +320,6 @@ def tiered_obs(state: TieredState):
 
 COLD_MEMORY_KIND = "pinned_host"  # the HBM-relief memory the cold tier requests
 
-# One probe per process (keyed by device kind): whether the runtime exposes the
-# cold tier's host memory kind. A single warning is logged on the fallback —
-# per-leaf silent fallbacks hid "tiered" configs that actually landed in HBM.
-_PLACEMENT_CACHE: dict = {}
-
 
 def device_memory_kinds(dev) -> set:
     """Memory kinds one device exposes ({} on runtimes without the API)."""
@@ -254,42 +330,36 @@ def device_memory_kinds(dev) -> set:
 
 
 def resolve_cold_placement(devices=None) -> str:
-    """Where cold-tier leaves will actually live: ``'pinned_host'`` when the
-    runtime exposes that memory kind (TPU/GPU), else ``'device'`` (CPU tests —
-    one warning per process, and the resolved value is surfaced in the dry-run
-    ``rehearsal_buffer`` record and ``BuiltStep.meta`` so a silently
-    device-resident "tiered" config is visible)."""
+    """Where the cold tier's records live, decided by platform.
+
+    On the CPU it is ``'device'``: host and device memory are the same there,
+    and the CPU backend cannot compile memory-space annotations. On an
+    accelerator it is ``'pinned_host'``; a runtime that lacks that memory kind
+    is an error, never a silent fallback to HBM."""
     # probe a device THIS process can address: in a multi-host run the mesh's
     # device 0 belongs to process 0, and addressable_memories() on a remote
-    # device raises — which would silently resolve divergent placements across
-    # the SPMD processes
+    # device raises
     proc = jax.process_index()
     devs = [d for d in (list(devices) if devices is not None else [])
             if getattr(d, "process_index", proc) == proc]
     dev = devs[0] if devs else jax.local_devices()[0]
-    cache_key = getattr(dev, "device_kind", None) or dev.platform
-    if cache_key in _PLACEMENT_CACHE:
-        return _PLACEMENT_CACHE[cache_key]
+    if dev.platform == "cpu":
+        return "device"
     kinds = device_memory_kinds(dev)
-    placement = COLD_MEMORY_KIND if COLD_MEMORY_KIND in kinds else "device"
-    if placement == "device":
-        from repro.utils.logging import get_logger
-
-        get_logger("repro.buffer").warning(
-            "tiered cold tier: %r memory kind unavailable on %s (kinds: %s); "
-            "cold records stay DEVICE-resident — capacity relief is disabled",
-            COLD_MEMORY_KIND, cache_key, sorted(kinds) or "none")
-    _PLACEMENT_CACHE[cache_key] = placement
-    return placement
+    if COLD_MEMORY_KIND not in kinds:
+        raise RuntimeError(
+            f"tiered cold tier: {dev.device_kind} exposes no {COLD_MEMORY_KIND!r} "
+            f"memory (kinds: {sorted(kinds) or 'none'}); the cold tier does not "
+            f"fall back to device memory")
+    return COLD_MEMORY_KIND
 
 
 def cold_shardings(state: TieredState, mesh, dp_axes):
-    """NamedShardings for a distributed TieredState (leading worker axis over dp),
-    requesting host (``pinned_host``) memory for the cold tier's leaves on runtimes
-    that support memory kinds — the actual HBM-relief mechanism on TPU. Falls back
-    to device placement where the memory kind is unavailable (CPU tests); the
-    probe runs once per process and logs a single warning on fallback
-    (``resolve_cold_placement``)."""
+    """NamedShardings for a distributed TieredState (leading worker axis over
+    dp). The cold tier's record leaves go to ``resolve_cold_placement``'s
+    memory: ``pinned_host`` on an accelerator, so its capacity is not bounded
+    by HBM. Its per-bucket counters and per-record scalars stay in device
+    memory (``host_resident``)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     placement = resolve_cold_placement(mesh.devices.flat)
@@ -299,13 +369,14 @@ def cold_shardings(state: TieredState, mesh, dp_axes):
 
     def host(leaf):
         s = worker_axis(leaf)
-        if placement == COLD_MEMORY_KIND:
+        if placement == COLD_MEMORY_KIND and host_resident(leaf.shape[3:]):
             return s.with_memory_kind(COLD_MEMORY_KIND)
         return s
 
+    cold = jax.tree_util.tree_map(worker_axis, state.cold)
     return TieredState(
         hot=jax.tree_util.tree_map(worker_axis, state.hot),
-        cold=jax.tree_util.tree_map(host, state.cold),
+        cold=cold._replace(data=jax.tree_util.tree_map(host, state.cold.data)),
         stage=jax.tree_util.tree_map(worker_axis, state.stage),
         stage_labels=worker_axis(state.stage_labels),
         stage_valid=worker_axis(state.stage_valid),

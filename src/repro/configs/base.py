@@ -204,8 +204,8 @@ class RehearsalConfig:
     # Fused Pallas hot path for the tiered store (DESIGN.md §14): cold sampling
     # dequantizes int8 rows in VMEM on the gather, demotion flushes quantize +
     # scatter in one kernel. Bit-identical to the default XLA op chain (the
-    # parity pin in tests/test_tiered_fused.py); off by default until it has
-    # soaked on TPU.
+    # parity pin in tests/test_tiered_fused.py); off by default until its
+    # speed is measured on TPU. No effect on a cold tier in host memory.
     fused_kernels: bool = False
     # Record-field names, plumbed end to end (loss masking + Alg-1 bucketing).
     label_field: str = "labels"
@@ -464,7 +464,9 @@ class TrainConfig:
     linear_scaling: bool = True  # multiply LR by number of DP workers
     grad_clip: float = 1.0
     param_dtype: str = "float32"
-    compute_dtype: str = "bfloat16"  # AMP analogue (paper enables AMP)
+    # AMP analogue (the paper enables AMP). None: the platform's, bfloat16 on
+    # the TPU and float32 on the CPU (utils.platform.default_compute_dtype)
+    compute_dtype: Optional[str] = None
     remat: str = "dots"  # none | dots | full — activation checkpointing policy
     grad_compress: str = "none"  # none | int8 (error-feedback quantized all-reduce)
     zero1: bool = False  # shard optimizer state over the data axis

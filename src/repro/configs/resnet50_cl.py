@@ -21,21 +21,25 @@ class CNNConfig:
     bottleneck: bool = True
     image_size: int = 224
     channels: int = 3
+    # cifar: one 3x3 stride-1 conv (32 px inputs keep their resolution);
+    # imagenet: the published 7x7 stride-2 conv + 3x3 stride-2 max pool, so a
+    # 224 px input enters the first stage at 56 px
+    stem: str = "cifar"
 
 
 def full() -> CNNConfig:
     return CNNConfig(name="resnet50-cl", variant="resnet50", stage_blocks=(3, 4, 6, 3),
-                     bottleneck=True)
+                     bottleneck=True, stem="imagenet")
 
 
 def resnet18() -> CNNConfig:
     return CNNConfig(name="resnet18-cl", variant="resnet18", stage_blocks=(2, 2, 2, 2),
-                     bottleneck=False)
+                     bottleneck=False, stem="imagenet")
 
 
 def ghostnet() -> CNNConfig:
     return CNNConfig(name="ghostnet50-cl", variant="ghostnet", stage_blocks=(2, 2, 4, 2),
-                     bottleneck=False)
+                     bottleneck=False, stem="imagenet")
 
 
 def reduced(num_classes: int = 40) -> CNNConfig:

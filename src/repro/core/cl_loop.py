@@ -35,6 +35,11 @@ class CLRunResult:
     # per-key {last, mean, max, n} over the ``obs/*`` gauges folded into
     # ``history`` (None unless the run had ``run.obs.enabled``)
     obs: Optional[Dict[str, Dict[str, float]]] = None
+    # the pjit backend's ``BuiltStep.meta`` (n_dp, cold_placement, ...); the
+    # carry backend gives a tiered run's tiering and cold_placement ('device')
+    step_meta: Optional[Dict[str, Any]] = None
+    # the rehearsal buffer as training left it (None when nothing was stored)
+    buffer: Any = None
 
 
 def run_continual(

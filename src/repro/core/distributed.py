@@ -65,17 +65,20 @@ def _exchange(items, valid, axis_names):
     return recv, recv_valid
 
 
-def sample_global(state, key, r: int, axis_names, exchange: str, rcfg=None):
+def sample_global(state, key, r: int, axis_names, exchange: str, rcfg=None, *,
+                  cold_host: bool = False):
     """Per-worker body (inside shard_map). Returns (reps [r, ...], valid bool[r]).
 
     ``state`` is a BufferState or TieredState; ``rcfg`` selects the sampling policy
-    (None ⇒ the paper's uniform-over-filled reservoir rule)."""
+    (None ⇒ the paper's uniform-over-filled reservoir rule); ``cold_host``: a
+    tiered store's cold records live in host memory."""
     if axis_names is None or exchange == "local":
-        return buffer_api.buffer_sample(state, key, r, rcfg)
+        return buffer_api.buffer_sample(state, key, r, rcfg, cold_host=cold_host)
 
     n = jax.lax.psum(1, axis_names)  # number of peers in the exchange group
     k_draw, k_pick = jax.random.split(key)
-    items, valid = buffer_api.buffer_sample(state, k_draw, n, rcfg)
+    items, valid = buffer_api.buffer_sample(state, k_draw, n, rcfg,
+                                            cold_host=cold_host)
     recv, recv_valid = _exchange(items, valid, axis_names)
     # keep a uniformly random valid r-subset of the n received candidates
     scores = jax.random.uniform(k_pick, (n,)) + jnp.where(recv_valid, 0.0, 1e3)
@@ -103,6 +106,8 @@ def issue_sample(
     rcfg,
     axis_names=None,
     exchange: str = "full",
+    *,
+    cold_host: bool = False,
 ) -> Tuple[Any, PendingSample]:
     """Producer half of the paper's ``RehearsalBuffer.update`` primitive, per worker:
     push candidates from the incoming mini-batch (Alg. 1), then launch the global
@@ -113,10 +118,11 @@ def issue_sample(
     *previous* ``PendingSample`` for training (pipelined mode), XLA's latency-hiding
     scheduler overlaps this exchange with the backward pass (DESIGN.md §3)."""
     k_up, k_samp = jax.random.split(key)
-    new_state = buffer_api.buffer_update(state, items, labels, k_up, rcfg)
+    new_state = buffer_api.buffer_update(state, items, labels, k_up, rcfg,
+                                         cold_host=cold_host)
     reps, valid = sample_global(
-        new_state, k_samp, rcfg.num_representatives, axis_names, exchange, rcfg
-    )
+        new_state, k_samp, rcfg.num_representatives, axis_names, exchange, rcfg,
+        cold_host=cold_host)
     return new_state, PendingSample(reps, valid)
 
 
@@ -156,11 +162,12 @@ def update_and_sample(
 
 
 def _squeeze0(tree):
-    return jax.tree_util.tree_map(lambda x: x[0], tree)
+    # a reshape, not x[0]: on a host-resident leaf it stays a bitcast
+    return jax.tree_util.tree_map(lambda x: x.reshape(x.shape[1:]), tree)
 
 
 def _unsqueeze0(tree):
-    return jax.tree_util.tree_map(lambda x: x[None], tree)
+    return jax.tree_util.tree_map(lambda x: x.reshape((1,) + x.shape), tree)
 
 
 def make_sharded_update(mesh, dp_axes: Tuple[str, ...], rcfg, exchange: str = "full",
@@ -172,9 +179,16 @@ def make_sharded_update(mesh, dp_axes: Tuple[str, ...], rcfg, exchange: str = "f
     batch leaves are globally batched on axis 0. The returned fn must be called
     under ``mesh`` (inside or outside jit). ``label_field=None`` inherits
     ``rcfg.label_field``.
+
+    A tiered store's cold records sit in the platform's cold-tier memory
+    (``tiered.resolve_cold_placement``); when that is host memory, the body
+    moves only the sampled and the demoted rows across memory kinds.
     """
+    from repro.buffer import tiered as tiered_mod
+
     label_field = buffer_api.resolve_field(label_field, rcfg, "label_field", "labels")
-    dp = P(dp_axes)
+    cold_host = bool(rcfg.tiered) and tiered_mod.resolve_cold_placement(
+        mesh.devices.flat) == tiered_mod.COLD_MEMORY_KIND
     exchange_axes = None
     if exchange == "full":
         exchange_axes = dp_axes if len(dp_axes) > 1 else dp_axes[0]
@@ -191,7 +205,8 @@ def make_sharded_update(mesh, dp_axes: Tuple[str, ...], rcfg, exchange: str = "f
         # per-worker RNG stream: fold in the linearised dp index
         idx = jax.lax.axis_index(dp_axes if len(dp_axes) > 1 else dp_axes[0])
         k = jax.random.fold_in(key, idx)
-        new_state, pending = issue_sample(state, items, labels, k, rcfg, axes, exchange)
+        new_state, pending = issue_sample(state, items, labels, k, rcfg, axes,
+                                          exchange, cold_host=cold_host)
         reps, valid = consume_reps(pending, label_field)
         return _unsqueeze0(new_state), _unsqueeze0(reps), valid[None]
 

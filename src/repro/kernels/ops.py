@@ -1,8 +1,8 @@
 """Public jit'd wrappers for the Pallas kernels.
 
 Layout adaptation + padding + interpret-mode dispatch live here; model code calls
-these, never the kernels directly. On CPU (this container) ``interpret=True`` runs the
-kernel bodies in Python for correctness validation; on TPU the same calls lower to
+these, never the kernels directly. On the CPU ``interpret=True`` runs the kernel
+bodies in Python for correctness validation; on TPU the same calls lower to
 Mosaic.
 """
 from __future__ import annotations
@@ -19,7 +19,9 @@ from repro.kernels import ssd_scan as _ssd
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    # the CPU runs the kernel bodies in the Pallas interpreter; every other
+    # backend compiles them (Mosaic on the TPU) or fails — never interprets
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("window", "causal", "block_q", "block_k",
@@ -113,9 +115,9 @@ def gather_dequant(q_table, scales_table, rows, dtype=jnp.float32, *,
     them in VMEM on the way out — bit-identical to gather-then-``dequantize``
     but with no fp-width HBM intermediate (DESIGN.md §14).
 
-    q_table int8 [R, L]; scales_table f32 [R, 1]; rows i32[S] (clamped into
-    range — sampling indices are always in-range, validity travels as a mask).
-    Returns [S, L] ``dtype``."""
+    q_table int8 [R, W, 128] (lane-dense records); scales_table f32 [R, 1];
+    rows i32[S] (clamped into range — sampling indices are always in-range,
+    validity travels as a mask). Returns [S, W, 128] ``dtype``."""
     interpret = _default_interpret() if interpret is None else interpret
     r = q_table.shape[0]
     idx = jnp.clip(rows, 0, r - 1)
@@ -134,8 +136,9 @@ def encode_scatter(q_table, scales_table, x, rows, *, row_tile: int = 8,
     keeps the table in place) — bit-identical to ``quantize``-then-scatter but
     with no encoded-batch intermediate (DESIGN.md §14).
 
-    q_table int8 [R, L]; scales_table f32 [R, 1]; x fp [S, L];
-    rows i32[S] (<0 or >= R ⇒ dropped). Returns (new_q_table, new_scales_table).
+    q_table int8 [R, W, 128] (lane-dense records); scales_table f32 [R, 1];
+    x fp [S, W, 128]; rows i32[S] (<0 or >= R ⇒ dropped). Returns
+    (new_q_table, new_scales_table).
     """
     interpret = _default_interpret() if interpret is None else interpret
     new_q, row_scales = _ro.encode_scatter_rows(q_table, x, rows,

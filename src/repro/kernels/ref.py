@@ -90,12 +90,14 @@ def gather_dequant_rows_ref(q_table, scales_table, rows, dtype=jnp.float32):
     rows and their scales, THEN dequantize the whole batch (the fp-width HBM
     intermediate the fused kernel avoids).
 
-    q_table int8 [R, L]; scales_table f32 [R, 1]; rows i32[S] (clamped).
-    Returns [S, L] ``dtype``.
+    q_table int8 [R, ...] (one record per row); scales_table f32 [R, 1];
+    rows i32[S] (clamped). Returns [S, ...] ``dtype``.
     """
     r = q_table.shape[0]
     idx = jnp.clip(rows, 0, r - 1)
-    return dequantize_rows_ref(q_table[idx], scales_table[idx], dtype)
+    q = q_table[idx]
+    x = dequantize_rows_ref(q.reshape(q.shape[0], -1), scales_table[idx], dtype)
+    return x.reshape(q.shape)
 
 
 def encode_scatter_rows_ref(q_table, scales_table, x, rows):
@@ -103,10 +105,11 @@ def encode_scatter_rows_ref(q_table, scales_table, x, rows):
     whole staged batch, THEN scatter rows + scales (the encoded-batch
     intermediate the fused kernel avoids).
 
-    q_table int8 [R, L]; scales_table f32 [R, 1]; x fp [S, L];
-    rows i32[S] (<0 or >= R ⇒ dropped). Returns (new_q_table, new_scales_table).
+    q_table int8 [R, ...] (one record per row); scales_table f32 [R, 1];
+    x fp [S, ...]; rows i32[S] (<0 or >= R ⇒ dropped). Returns
+    (new_q_table, new_scales_table).
     """
-    q, s = quantize_rows_ref(x)
+    q, s = quantize_rows_ref(x.reshape(x.shape[0], -1))
     safe = jnp.where(rows >= 0, rows, q_table.shape[0])  # OOB ⇒ dropped
-    return (q_table.at[safe].set(q, mode="drop"),
+    return (q_table.at[safe].set(q.reshape(x.shape), mode="drop"),
             scales_table.at[safe].set(s, mode="drop"))

@@ -198,11 +198,11 @@ def _update_sample_tiled(buffer, cands, cand_rows, samp_rows, *, row_tile: int,
         num_scalar_prefetch=2,
         grid=(ct + st,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),  # buffer table, row-DMA'd
+            pl.BlockSpec(memory_space=pl.ANY),  # buffer table, row-DMA'd
             pl.BlockSpec((row_tile, l), cand_index),
         ],
         out_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((row_tile, l), reps_index),
         ],
         scratch_shapes=[pltpu.SemaphoreType.DMA((row_tile,))],
@@ -260,6 +260,11 @@ def rehearsal_pipelined_step(buffer, pending_reps, cands, cand_rows, samp_rows, 
 # ---------------------------------------------------------------------------
 # dequant-on-gather: cold-tier sampling without the fp HBM intermediate
 # ---------------------------------------------------------------------------
+#
+# Both tiered kernels take the int8 table in the lane-dense record layout
+# [R, W, 128] (``core.compression``): one record is a whole [W, 128] slab, so a
+# row DMA slices only the untiled leading dim. A [R, L] int8 table packs four
+# records into each 32-bit word of a tile, and no DMA can address one of them.
 
 
 def _gather_dequant_kernel(rows_ref, q_any, scales_ref, out_ref, qtile, sems,
@@ -282,11 +287,12 @@ def _gather_dequant_kernel(rows_ref, q_any, scales_ref, out_ref, qtile, sems,
 
 def gather_dequant_rows(q_table, row_scales, rows, dtype=jnp.float32, *,
                         row_tile: int = 8, interpret: bool = False):
-    """q_table int8 [R, L]; row_scales f32 [S, 1] (pre-gathered per sampled row);
-    rows i32[S] (clamped into range). Returns fp ``dtype`` [S, L]: the sampled
-    cold rows, dequantized in VMEM on the way out — the int8 table is the only
-    full-width HBM read, and the fp batch the only full-width write."""
-    r, l = q_table.shape
+    """q_table int8 [R, W, C] (lane-dense records); row_scales f32 [S, 1]
+    (pre-gathered per sampled row); rows i32[S] (clamped into range). Returns
+    fp ``dtype`` [S, W, C]: the sampled cold rows, dequantized in VMEM on the
+    way out — the int8 table is the only full-width HBM read, and the fp
+    batch the only full-width write."""
+    r, w, c = q_table.shape
     s = rows.shape[0]
     st = _ceil_div(s, row_tile)
     pad = st * row_tile - s
@@ -294,17 +300,21 @@ def gather_dequant_rows(q_table, row_scales, rows, dtype=jnp.float32, *,
         rows = jnp.concatenate([rows, jnp.zeros((pad,), rows.dtype)])
         row_scales = jnp.concatenate(
             [row_scales, jnp.ones((pad, 1), row_scales.dtype)])
+    row_scales = row_scales.reshape(st * row_tile, 1, 1)
+
+    def tile_index(t, rows_ref):
+        return (t, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(st,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),  # int8 table, row-DMA'd
-            pl.BlockSpec((row_tile, 1), lambda t, rows_ref: (t, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),  # int8 table, row-DMA'd
+            pl.BlockSpec((row_tile, 1, 1), tile_index),
         ],
-        out_specs=[pl.BlockSpec((row_tile, l), lambda t, rows_ref: (t, 0))],
+        out_specs=[pl.BlockSpec((row_tile, w, c), tile_index)],
         scratch_shapes=[
-            pltpu.VMEM((row_tile, l), q_table.dtype),
+            pltpu.VMEM((row_tile, w, c), q_table.dtype),
             pltpu.SemaphoreType.DMA((row_tile,)),
         ],
     )
@@ -313,7 +323,7 @@ def gather_dequant_rows(q_table, row_scales, rows, dtype=jnp.float32, *,
     out, = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((st * row_tile, l), dtype)],
+        out_shape=[jax.ShapeDtypeStruct((st * row_tile, w, c), dtype)],
         interpret=interpret,
     )(rows, q_table, row_scales)
     return out[:s]
@@ -327,10 +337,13 @@ def gather_dequant_rows(q_table, row_scales, rows, dtype=jnp.float32, *,
 def _encode_scatter_kernel(rows_ref, q_any, x_ref, out_q_any, scales_ref,
                            qtile, sems, *, n: int, n_rows: int, tile: int):
     t = pl.program_id(0)
-    # row-wise symmetric int8 quantization — op-for-op the quantize.py kernel,
-    # so the fused flush is bit-identical to encode_batch + scatter
-    x = x_ref[...].astype(jnp.float32)  # [tile, L]
-    amax = jnp.max(jnp.abs(x), axis=1, keepdims=True)
+    # row-wise symmetric int8 quantization — op-for-op the quantize.py kernel
+    # (the row max taken lane-wise, then over the record's sublanes: max is
+    # exact in any order), so the fused flush is bit-identical to
+    # encode_batch + scatter
+    x = x_ref[...].astype(jnp.float32)  # [tile, W, C]
+    amax = jnp.max(jnp.max(jnp.abs(x), axis=2, keepdims=True), axis=1,
+                   keepdims=True)
     scale = jnp.maximum(amax, 1e-12) / 127.0
     qtile[...] = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
     scales_ref[...] = scale
@@ -350,35 +363,36 @@ def _encode_scatter_kernel(rows_ref, q_any, x_ref, out_q_any, scales_ref,
 
 def encode_scatter_rows(q_table, x, rows, *, row_tile: int = 8,
                         interpret: bool = False):
-    """q_table int8 [R, L] (updated in place via aliasing); x fp [S, L] staged
-    rows; rows i32[S] target rows (<0 or >= R ⇒ dropped). Returns
-    ``(new_q_table [R, L], row_scales f32 [S, 1])`` — the quantized rows land
-    directly in the table with no encoded-batch intermediate; the caller
-    scatters the (tiny) returned scales into its scale table."""
-    r, l = q_table.shape
+    """q_table int8 [R, W, C] (lane-dense records, updated in place via
+    aliasing); x fp [S, W, C] staged rows; rows i32[S] target rows (<0 or
+    >= R ⇒ dropped). Returns ``(new_q_table [R, W, C], row_scales f32 [S, 1])``
+    — the quantized rows land directly in the table with no encoded-batch
+    intermediate; the caller scatters the (tiny) returned scales into its
+    scale table."""
+    r, w, c = q_table.shape
     s = x.shape[0]
     st = _ceil_div(s, row_tile)
     pad = st * row_tile - s
     if pad:
-        x = jnp.concatenate([x, jnp.zeros((pad, l), x.dtype)])
+        x = jnp.concatenate([x, jnp.zeros((pad, w, c), x.dtype)])
         rows = jnp.concatenate([rows, jnp.full((pad,), -1, rows.dtype)])
 
-    def x_index(t, rows_ref):
-        return (t, 0)
+    def tile_index(t, rows_ref):
+        return (t, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(st,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),  # int8 table, row-DMA'd
-            pl.BlockSpec((row_tile, l), x_index),
+            pl.BlockSpec(memory_space=pl.ANY),  # int8 table, row-DMA'd
+            pl.BlockSpec((row_tile, w, c), tile_index),
         ],
         out_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec((row_tile, 1), x_index),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((row_tile, 1, 1), tile_index),
         ],
         scratch_shapes=[
-            pltpu.VMEM((row_tile, l), q_table.dtype),
+            pltpu.VMEM((row_tile, w, c), q_table.dtype),
             pltpu.SemaphoreType.DMA((row_tile,)),
         ],
     )
@@ -388,10 +402,10 @@ def encode_scatter_rows(q_table, x, rows, *, row_tile: int = 8,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((r, l), q_table.dtype),
-            jax.ShapeDtypeStruct((st * row_tile, 1), jnp.float32),
+            jax.ShapeDtypeStruct((r, w, c), q_table.dtype),
+            jax.ShapeDtypeStruct((st * row_tile, 1, 1), jnp.float32),
         ],
         input_output_aliases={1: 0},  # q_table (after the prefetch arg) -> out 0
         interpret=interpret,
     )(rows, q_table, x)
-    return new_q, scales[:s]
+    return new_q, scales[:s].reshape(s, 1)

@@ -32,10 +32,10 @@ def rehearsal_buffer_cost(built, rcfg) -> dict:
     what the compiled step allocates. Tiered (``'host'``): the hot tier plus the
     raw demotion staging rows stay in HBM, while the cold tier holds
     ``K × cold_slots`` *int8* rows in host memory (per float leaf: 1 byte per
-    element + a 4-byte row scale — ``core.compression.compressed_spec``; int
-    leaves stored raw). The cold tier never appears in the compiled HLO (it is
-    host-resident), so it must be modeled here rather than read from XLA's
-    memory analysis.
+    element, padded to whole 128-lane rows, + a 4-byte row scale —
+    ``core.compression.compressed_spec``; int leaves stored raw). The cold
+    tier never appears in the compiled HLO (it is host-resident), so it must
+    be modeled here rather than read from XLA's memory analysis.
 
     Strategy aux fields (DER stored logits, grasp_embed embeddings) are part
     of the record spec the builder extends (``built.meta['aux_fields']``), so
@@ -46,6 +46,8 @@ def rehearsal_buffer_cost(built, rcfg) -> dict:
     if built.meta.get("mode", "off") == "off":
         return {"mode": "off", "hot_hbm_bytes": 0, "cold_host_bytes": 0,
                 "total_bytes": 0, "rows_per_bucket": 0}
+    from repro.core.compression import LANES, lane_rows
+
     reps_s = built.args[3]  # [n_dp, r, ...] record structure
     aux_fields = dict(built.meta.get("aux_fields", {}))
     raw_row = cold_row = 0
@@ -57,7 +59,8 @@ def rehearsal_buffer_cost(built, rcfg) -> dict:
         itemsize = jnp.dtype(leaf.dtype).itemsize
         raw_row += n * itemsize
         if jnp.issubdtype(leaf.dtype, jnp.floating):
-            cold_row += n + 4  # int8 q + one f32 scale per row-leaf
+            # int8 slab (padded to whole 128-lane rows) + one f32 scale
+            cold_row += lane_rows(n) * LANES + 4
         else:
             cold_row += n * itemsize
     aux_row = sum(aux_fields.values())
@@ -78,9 +81,8 @@ def rehearsal_buffer_cost(built, rcfg) -> dict:
 
     return {
         "mode": "tiered" if cold_slots else "flat",
-        # where the cold bytes actually land: 'pinned_host' when the runtime
-        # exposes the memory kind, 'device' when the fallback kicked in — a
-        # "tiered" config whose cold tier silently stayed in HBM is visible here
+        # where the cold bytes land: 'pinned_host' on an accelerator,
+        # 'device' on the CPU (tiered.resolve_cold_placement)
         "cold_placement": resolve_placement(rcfg) if cold_slots else None,
         "raw_row_bytes": raw_row,
         "cold_row_bytes": cold_row,

@@ -27,8 +27,7 @@ def make_mesh(shape, axes):
 
 def memory_kinds(mesh) -> set:
     """Memory kinds addressable by the mesh's devices (e.g. {'device',
-    'pinned_host'} on TPU, {'unpinned_host'} on CPU) — the probe behind the
-    tiered cold tier's host placement (repro.buffer.tiered)."""
+    'pinned_host', 'unpinned_host'} on a TPU), for the launch log."""
     from repro.buffer.tiered import device_memory_kinds
 
     kinds = set()

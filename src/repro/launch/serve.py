@@ -20,8 +20,9 @@ from repro.configs import get_config, get_reduced
 from repro.launch.mesh import make_mesh
 from repro.models import StackCtx, build_model
 from repro.parallel import make_shard_fn
-from repro.utils.logging import get_logger
 from repro.utils.compat import set_mesh
+from repro.utils.logging import get_logger
+from repro.utils.platform import enable_compile_cache
 
 log = get_logger("repro.serve")
 
@@ -57,6 +58,7 @@ def main(argv=None):
                     help="serve Prometheus text gauges at /metrics on PORT "
                          "(0 = OS-assigned; default: no endpoint)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     from repro import obs as obs_mod
     registry = server = None

@@ -49,6 +49,7 @@ from repro.parallel import (
     params_shardings,
 )
 from repro.parallel.sharding import make_moe_apply
+from repro.utils.platform import compute_dtype_of
 from repro.utils.trees import tree_cast
 
 MAX_SLOTS = 1024
@@ -141,7 +142,7 @@ def build_train_step(
     model = build_model(cfg)
     dp = dp_axes(mesh)
     n_dp = int(np.prod([mesh.shape[a] for a in dp]))
-    compute_dtype = jnp.bfloat16 if tcfg.compute_dtype == "bfloat16" else jnp.float32
+    compute_dtype = compute_dtype_of(tcfg.compute_dtype)
     from repro.models.attention import ATTN_IMPL
     ATTN_IMPL["mode"] = tcfg.attn_impl
     ctx = StackCtx(cfg=cfg, shard=make_shard_fn(mesh, tcfg.sequence_parallel),
@@ -477,7 +478,7 @@ def _rehearsal_shardings(params_s, opt_s, buffer_sh, reps_s, batch_s, cfg, mesh,
 def build_prefill_step(run: RunConfig, mesh) -> BuiltStep:
     cfg, shape = run.model, run.shape
     model = build_model(cfg)
-    compute_dtype = jnp.bfloat16 if run.train.compute_dtype == "bfloat16" else jnp.float32
+    compute_dtype = compute_dtype_of(run.train.compute_dtype)
     n_dp = int(np.prod([mesh.shape[a] for a in dp_axes(mesh)]))
     dp_sh = n_dp if (shape.global_batch * shape.seq_len) % n_dp == 0 else 1
     from repro.models.attention import ATTN_IMPL
@@ -505,7 +506,7 @@ def build_prefill_step(run: RunConfig, mesh) -> BuiltStep:
 def build_decode_step(run: RunConfig, mesh) -> BuiltStep:
     cfg, shape = run.model, run.shape
     model = build_model(cfg)
-    compute_dtype = jnp.bfloat16 if run.train.compute_dtype == "bfloat16" else jnp.float32
+    compute_dtype = compute_dtype_of(run.train.compute_dtype)
     n_dp = int(np.prod([mesh.shape[a] for a in dp_axes(mesh)]))
     dp_sh = n_dp if shape.global_batch % n_dp == 0 else 1
     from repro.models.attention import ATTN_IMPL

@@ -5,7 +5,8 @@ the CLI builds a ``RunConfig`` (+ ``ScenarioConfig``) and a token
 class-incremental scenario, and ``ContinualTrainer``'s pjit backend does what
 this file used to hand-wire — ``build_train_step``, state materialisation,
 prefetching, checkpointing, per-task eval (DESIGN.md §7). ``--mesh 1x1`` runs
-the same program single-device (CPU) that ``--mesh 16x16`` runs on a pod.
+the same program on one device that ``--mesh 16x16`` runs on a pod. The
+compute dtype follows the platform: bfloat16 on the TPU, float32 on the CPU.
 
 Example (CPU, reduced arch):
   PYTHONPATH=src python -m repro.launch.train --arch smollm-135m --reduced \\
@@ -30,6 +31,7 @@ from repro.launch.mesh import make_mesh
 from repro.scenario import ContinualTrainer, TokenClassIncremental
 from repro.scenario.trainer import materialize_state  # noqa: F401  (back-compat)
 from repro.utils.logging import get_logger
+from repro.utils.platform import compute_dtype_of, enable_compile_cache
 
 log = get_logger("repro.train")
 
@@ -84,6 +86,7 @@ def main(argv=None):
                     help="wall-clock step budget (s); overruns flag the next "
                          "exchange as straggling (bounded-staleness reuse)")
     args = ap.parse_args(argv)
+    cache_dir = enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     strategy = args.strategy or (
@@ -95,9 +98,11 @@ def main(argv=None):
     run = RunConfig(
         model=cfg,
         shape=shape,
+        # remat="full": at real sequence lengths the per-layer attention
+        # scores that the "dots" policy saves outgrow a chip's HBM
         train=TrainConfig(optimizer=args.optimizer, peak_lr=args.lr,
                           warmup_steps=20, linear_scaling=False,
-                          compute_dtype="float32" if m * d == 1 else "bfloat16"),
+                          remat="full"),
         rehearsal=RehearsalConfig(num_buckets=max(args.tasks, 2), mode=args.mode,
                                   slots_per_bucket=args.slots_per_bucket,
                                   policy=args.policy, tiering=args.tiering,
@@ -120,9 +125,11 @@ def main(argv=None):
     )
     scenario = TokenClassIncremental(run.scenario)
 
-    log.info("arch=%s params=%.1fM mesh=%s mode=%s strategy=%s",
-             cfg.name, cfg.param_count() / 1e6, dict(mesh.shape), args.mode,
-             strategy)
+    log.info("arch=%s params=%.1fM mesh=%s mode=%s strategy=%s dtype=%s "
+             "compile_cache=%s", cfg.name, cfg.param_count() / 1e6,
+             dict(mesh.shape), args.mode, strategy,
+             compute_dtype_of(run.train.compute_dtype),
+             cache_dir)
     if strategy in ("der", "der_pp") and args.der_top_k:
         log.info("der: storing top-%d logit (val,idx) pairs per position "
                  "(alpha=%.2f beta=%.2f)", args.der_top_k, args.der_alpha,

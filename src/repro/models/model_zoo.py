@@ -63,6 +63,50 @@ def cross_entropy(logits, labels, mask=None, label_smoothing: float = 0.0):
     return jnp.sum(jnp.where(valid, nll, 0.0)) / denom
 
 
+# Largest float32 logits block the decoder loss materialises at once. Above it
+# (long sequences over a big vocabulary: SmolLM's 49152 x 2048 tokens x 15 rows
+# is 6 GB) the loss runs the head + CE over several sequence chunks, each
+# rematerialised in the backward pass, so the full [B, S, V] logits never
+# exist. Below it the whole sequence is one chunk.
+LOGITS_BLOCK_BYTES = 512 << 20
+
+
+def _ce_chunk(b: int, s: int, v: int) -> int:
+    chunk = s
+    while chunk % 2 == 0 and b * chunk * v * 4 > LOGITS_BLOCK_BYTES:
+        chunk //= 2
+    return chunk
+
+
+def chunked_cross_entropy(head, hidden, labels, chunk: int):
+    """``cross_entropy(head(hidden), labels)`` computed ``chunk`` positions at
+    a time: hidden [B, S, D] -> per-chunk logits [B, chunk, V] in f32, summed
+    NLL and valid counts carried through a scan, each chunk rematerialised in
+    the backward pass. With ``chunk == S`` it is bit-identical to the
+    one-block form; with more chunks, equal up to summation order."""
+    b, s, d = hidden.shape
+    n = s // chunk
+    hs = jnp.swapaxes(hidden.reshape(b, n, chunk, d), 0, 1)
+    ls = jnp.swapaxes(labels.reshape(b, n, chunk), 0, 1)
+
+    @jax.checkpoint
+    def piece(h, lab):
+        logits = head(h).astype(jnp.float32)
+        valid = lab >= 0
+        logz = jax.scipy.special.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, jnp.maximum(lab, 0)[..., None],
+                                   axis=-1)[..., 0]
+        return jnp.sum(jnp.where(valid, logz - gold, 0.0)), jnp.sum(valid)
+
+    def body(carry, xs):
+        nll, cnt = piece(*xs)
+        return (carry[0] + nll, carry[1] + cnt), None
+
+    (nll, cnt), _ = jax.lax.scan(body, (jnp.zeros((), jnp.float32),
+                                        jnp.zeros((), jnp.int32)), (hs, ls))
+    return nll / jnp.maximum(cnt, 1)
+
+
 # ---------------------------------------------------------------------------
 # Input specs per family — ShapeDtypeStruct stand-ins (no allocation; dry-run contract)
 # ---------------------------------------------------------------------------
@@ -121,8 +165,11 @@ def _build_decoder(cfg) -> LM:
         return tf.forward_decoder(params, batch, cfg, ctx)
 
     def loss(params, batch, ctx: StackCtx, aux_weight: float = DEFAULT_AUX_WEIGHT):
-        logits, aux = forward(params, batch, ctx)
-        ce = cross_entropy(logits, batch["labels"])
+        hidden, aux = tf.hidden_decoder(params, batch, cfg, ctx)
+        b, s, _ = hidden.shape
+        ce = chunked_cross_entropy(
+            lambda h: tf.logits_from(params, h, cfg, ctx), hidden,
+            batch["labels"], _ce_chunk(b, s, cfg.vocab_size))
         metrics = {"ce": ce, "aux": aux}
         return ce + aux_weight * aux, metrics
 

@@ -124,7 +124,8 @@ def init_cnn(key, cfg):
     init_blk, _, expand = _BLOCKS[cfg.variant]
     keys = jax.random.split(key, 2 + sum(cfg.stage_blocks))
     ki = iter(keys)
-    params = {"stem": _conv_init(next(ki), 3, 3, cfg.channels, cfg.width),
+    k_stem = 7 if cfg.stem == "imagenet" else 3
+    params = {"stem": _conv_init(next(ki), k_stem, k_stem, cfg.channels, cfg.width),
               "gn_stem": init_groupnorm(cfg.width)}
     cin = cfg.width
     stages = []
@@ -148,7 +149,11 @@ def cnn_outputs(params, images, cfg):
     step and shared by the loss, DER logit storage, and the GRASP
     embedding-space prototype distances (DESIGN.md §9)."""
     _, apply_blk, _ = _BLOCKS[cfg.variant]
-    x = jax.nn.relu(groupnorm(params["gn_stem"], conv(images, params["stem"])))
+    stride = 2 if cfg.stem == "imagenet" else 1
+    x = jax.nn.relu(groupnorm(params["gn_stem"], conv(images, params["stem"], stride)))
+    if cfg.stem == "imagenet":
+        x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 3, 3, 1),
+                                  (1, 2, 2, 1), "SAME")
     for s, blocks in enumerate(params["stages"]):
         for b, blk in enumerate(blocks):
             stride = 2 if (b == 0 and s > 0) else 1

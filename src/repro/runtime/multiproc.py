@@ -47,9 +47,7 @@ def distributed_available() -> Tuple[bool, str]:
         return False, f"jax not importable: {e}"
     if not hasattr(jax, "distributed"):
         return False, "jax.distributed missing in this jax build"
-    try:
-        jax.config.read("jax_cpu_collectives_implementation")
-    except AttributeError:
+    if "jax_cpu_collectives_implementation" not in jax.config.values:
         return False, "no jax_cpu_collectives_implementation config (gloo unavailable)"
     return True, "ok"
 

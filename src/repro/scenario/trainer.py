@@ -526,7 +526,17 @@ class ContinualTrainer:
                            task_runtimes=runtimes, final_accuracy=final,
                            history=history,
                            restarts=int(res_stats.get("restarts", 0)),
-                           resilience_stats=res_stats or None)
+                           resilience_stats=res_stats or None,
+                           step_meta=self._carry_meta(),
+                           buffer=carry.buffer)
+
+    def _carry_meta(self):
+        """The carry backend's counterpart of ``BuiltStep.meta`` for a tiered
+        buffer: it runs on one device with both tiers in device memory (the
+        table the fused kernels address), whatever the platform."""
+        if self.rcfg is None or not self.rcfg.tiered:
+            return None
+        return {"tiering": self.rcfg.tiering, "cold_placement": "device"}
 
     # ------------------------------------------------------------------ pjit
     def _fit_pjit(self):
@@ -698,7 +708,8 @@ class ContinualTrainer:
                            task_runtimes=runtimes, final_accuracy=final,
                            history=history,
                            restarts=int(res_stats.get("restarts", 0)),
-                           resilience_stats=res_stats or None)
+                           resilience_stats=res_stats or None,
+                           step_meta=built.meta, buffer=buffer)
 
 
 # ---------------------------------------------------------------------------
@@ -730,8 +741,8 @@ def materialize_state(built, run, mesh, key, exchange: str = "full"):
         lambda s: jax.ShapeDtypeStruct(s.shape[2:], s.dtype), reps_struct)
     if built.meta.get("tiering", "off") != "off":
         # tiered: the config is authoritative for hot/cold/stage sizes (mirrors
-        # build_train_step); out_shardings place the cold tier in pinned_host
-        # where available (tiered.cold_shardings), device elsewhere
+        # build_train_step); out_shardings place the cold tier's records in
+        # the platform's cold memory (tiered.cold_shardings)
         buffer = jax.jit(
             lambda: dist.init_distributed_from_config(item_s, rcfg, n_dp),
             out_shardings=built.shardings[2])()

@@ -1,67 +1,28 @@
-"""JAX version-compat shims (single import point for version-sensitive APIs).
+"""Mesh and ``shard_map`` helpers shared by the step builders and the tests.
 
-The codebase targets the modern jax surface (``jax.shard_map``,
-``jax.sharding.AxisType``, ``jax.set_mesh``) but must also run on older 0.4.x
-runtimes where those spell differently or don't exist:
-
-  * ``AxisType`` / ``make_mesh(..., axis_types=...)``  — absent pre-0.5; fall back
-    to a plain ``jax.make_mesh`` (all axes behave as Auto there anyway).
-  * ``jax.shard_map``                                  — pre-0.5 it lives in
-    ``jax.experimental.shard_map`` and spells the replication check ``check_rep``
-    instead of ``check_vma``.
-  * ``jax.set_mesh``                                   — pre-0.5 the Mesh object
-    itself is the context manager.
-
-Everything else in the repo imports these three names from here and never
-touches the version-sensitive spellings directly (tests included).
+``make_mesh`` gives every axis the ``Auto`` type, and ``shard_map`` turns the
+replication check off by default: the rehearsal bodies return per-worker
+buffers that are varying along the data axis by construction.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5: typed mesh axes
-    from jax.sharding import AxisType  # noqa: F401
-
-    HAS_AXIS_TYPE = True
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
-    HAS_AXIS_TYPE = False
+set_mesh = jax.set_mesh
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the runtime supports them."""
+    """``jax.make_mesh`` with ``Auto`` axis types."""
     shape, axes = tuple(shape), tuple(axes)
-    if HAS_AXIS_TYPE:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-if hasattr(jax, "set_mesh"):
-    set_mesh = jax.set_mesh
-else:  # pre-0.5: ``with mesh:`` sets the thread-local physical mesh
-
-    def set_mesh(mesh):
-        return mesh
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def cost_analysis(compiled):
-    """Compiled-module cost analysis as a flat dict (0.4.x returns a one-element
-    list of dicts; newer jax returns the dict directly)."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """Compiled-module cost analysis as a dict (empty when unavailable)."""
+    return compiled.cost_analysis() or {}
 
 
-if hasattr(jax, "shard_map"):
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-
-else:
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-        return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma)
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check_vma)

@@ -49,7 +49,7 @@ def test_encode_decode_roundtrip_buffer_records(b, feat, seq, scale, scalar_floa
     enc = C.encode_batch(batch, spec)
     # stored form is int8 + one f32 scale per record for every float leaf
     assert enc["emb"]["q"].dtype == jnp.int8
-    assert enc["emb"]["q"].shape == (b, feat * 4)
+    assert enc["emb"]["q"].shape == (b, -(-feat * 4 // 128), 128)  # lane slabs
     assert enc["emb"]["scale"].shape == (b, 1)
     assert enc["tokens"]["raw"].dtype == jnp.int32
     dec = C.decode_batch(enc, spec)

@@ -78,21 +78,24 @@ def test_bufferstate_three_field_construction_still_works():
 
 # rb.local_update(c=6) + rb.local_sample(n=5) on a 2x8 buffer, keys fold_in(7, s)
 # and fold_in(7, 100+s): [sum(reps.x), sum(reps.label), valid.sum(), counts]
+# The traces were re-pinned when JAX made ``jax_threefry_partitionable`` the
+# default, which changes the random bits each key yields. Under
+# ``jax_threefry_partitionable=False`` the earlier traces still reproduce.
 _RAW_TRACE = [
-    [-4.6179, 6, 5, [2, 3]],
-    [-2.7655, 2, 5, [5, 5]],
-    [0.595, 4, 5, [8, 8]],
-    [1.0167, 5, 5, [8, 8]],
-    [1.8291, 3, 5, [8, 8]],
-    [1.9621, 9, 5, [8, 8]],
-    [-6.1588, 4, 5, [8, 8]],
-    [10.3011, 6, 5, [8, 8]],
+    [-2.7847, 10, 5, [6, 4]],
+    [9.0489, 8, 5, [7, 8]],
+    [-3.8469, 5, 5, [8, 8]],
+    [-0.8515, 6, 5, [8, 8]],
+    [-0.5766, 7, 5, [8, 8]],
+    [3.6378, 7, 5, [8, 8]],
+    [-1.9562, 8, 5, [8, 8]],
+    [-8.5148, 10, 5, [8, 8]],
 ]
 
 # make_cl_step(mode='sync', exchange='local', seed=3) rep_checksum per step, and
 # the final sum|w| of the linear probe after 10 steps.
-_STEP_TRACE = [5.0, 4.0, 5.0, 6.0, 2.0, 7.0, 4.0, 6.0, 3.0, 8.0]
-_FINAL_W = 0.836892
+_STEP_TRACE = [5.0, 4.0, 8.0, 8.0, 9.0, 4.0, 3.0, 6.0, 5.0, 6.0]
+_FINAL_W = 0.867979
 
 
 def test_reservoir_matches_pinned_raw_trace():

@@ -166,19 +166,23 @@ def test_rehearsal_tiled_matches_single_row_path(r, l, c, s, tile, seed):
 # ---------------------------------------------------------------------------
 
 
+# The tiered kernels take int8 tables in the lane-dense record layout
+# [R, W, 128] (core.compression): W lane rows of 128 per record.
+
+
 @settings(deadline=None, max_examples=15)
 @given(
     r=st.integers(1, 40),
-    l=st.integers(1, 40),
+    w=st.integers(1, 3),
     s=st.integers(1, 16),
     tile=st.sampled_from([1, 4, 8]),
     seed=st.integers(0, 2**31 - 1),
 )
-def test_gather_dequant_matches_ref(r, l, s, tile, seed):
+def test_gather_dequant_matches_ref(r, w, s, tile, seed):
     """Fused gather+dequant == the two-pass oracle over ragged shapes (rows
     clamp; duplicates are reads, so always well-defined)."""
     key = jax.random.PRNGKey(seed)
-    q = jax.random.randint(key, (r, l), -127, 128, dtype=jnp.int8)
+    q = jax.random.randint(key, (r, w, 128), -127, 128, dtype=jnp.int8)
     scales = jax.random.uniform(jax.random.fold_in(key, 1), (r, 1),
                                 minval=1e-4, maxval=4.0)
     rows = jax.random.randint(jax.random.fold_in(key, 2), (s,), 0, r)
@@ -190,20 +194,20 @@ def test_gather_dequant_matches_ref(r, l, s, tile, seed):
 @settings(deadline=None, max_examples=15)
 @given(
     r=st.integers(1, 40),
-    l=st.integers(1, 40),
+    w=st.integers(1, 3),
     c=st.integers(1, 16),
     tile=st.sampled_from([1, 4, 8]),
     seed=st.integers(0, 2**31 - 1),
 )
-def test_encode_scatter_matches_ref(r, l, c, tile, seed):
+def test_encode_scatter_matches_ref(r, w, c, tile, seed):
     """Fused quantize+scatter == the two-pass oracle over ragged shapes and
     dropped (-1 and positive-OOB) targets; int8 payload pinned exact, scales to
     the kernel-vs-eager float tolerance (matching test_compression)."""
     key = jax.random.PRNGKey(seed)
-    q = jax.random.randint(key, (r, l), -127, 128, dtype=jnp.int8)
+    q = jax.random.randint(key, (r, w, 128), -127, 128, dtype=jnp.int8)
     scales = jax.random.uniform(jax.random.fold_in(key, 1), (r, 1),
                                 minval=1e-4, maxval=4.0)
-    x = jax.random.normal(jax.random.fold_in(key, 2), (c, l)) * 3
+    x = jax.random.normal(jax.random.fold_in(key, 2), (c, w, 128)) * 3
     rows = jax.random.randint(jax.random.fold_in(key, 3), (c,), -1, r + 2)
     gq, gs = ops.encode_scatter(q, scales, x, rows)
     wq, ws = ref.encode_scatter_rows_ref(q, scales, x, rows)
@@ -225,9 +229,10 @@ def test_encode_scatter_matches_ref(r, l, c, tile, seed):
 def test_encode_scatter_all_invalid_stage_is_identity():
     """An empty demotion stage (all rows dropped) must leave the cold table
     bit-identical — the step-0 tiered flush."""
-    q = jax.random.randint(jax.random.PRNGKey(0), (16, 12), -127, 128, dtype=jnp.int8)
+    q = jax.random.randint(jax.random.PRNGKey(0), (16, 2, 128), -127, 128,
+                           dtype=jnp.int8)
     scales = jax.random.uniform(jax.random.PRNGKey(1), (16, 1))
-    x = jax.random.normal(jax.random.PRNGKey(2), (6, 12))
+    x = jax.random.normal(jax.random.PRNGKey(2), (6, 2, 128))
     for bad in (jnp.full((6,), -1, jnp.int32), jnp.full((6,), 99, jnp.int32)):
         gq, gs = ops.encode_scatter(q, scales, x, bad)
         np.testing.assert_array_equal(np.asarray(gq), np.asarray(q))
@@ -236,18 +241,20 @@ def test_encode_scatter_all_invalid_stage_is_identity():
 
 def test_encode_scatter_duplicate_rows_last_write_wins():
     """Duplicate targets resolve in candidate order (the XLA scatter contract)."""
-    q = jnp.zeros((8, 4), jnp.int8)
+    q = jnp.zeros((8, 1, 128), jnp.int8)
     scales = jnp.ones((8, 1))
-    x = jnp.stack([jnp.full((4,), 10.0), jnp.full((4,), 20.0), jnp.full((4,), 30.0)])
+    x = jnp.stack([jnp.full((1, 128), 10.0), jnp.full((1, 128), 20.0),
+                   jnp.full((1, 128), 30.0)])
     rows = jnp.array([5, 5, 5], jnp.int32)
     gq, gs = ops.encode_scatter(q, scales, x, rows)
-    qr, sr = ref.quantize_rows_ref(x)
-    np.testing.assert_array_equal(np.asarray(gq[5]), np.asarray(qr[2]))
+    qr, sr = ref.quantize_rows_ref(x.reshape(3, -1))
+    np.testing.assert_array_equal(np.asarray(gq[5]).ravel(), np.asarray(qr[2]))
     np.testing.assert_allclose(np.asarray(gs[5]), np.asarray(sr[2]), rtol=1e-6)
 
 
 def test_gather_dequant_preserves_record_dtype():
-    q = jax.random.randint(jax.random.PRNGKey(3), (10, 8), -127, 128, dtype=jnp.int8)
+    q = jax.random.randint(jax.random.PRNGKey(3), (10, 1, 128), -127, 128,
+                           dtype=jnp.int8)
     scales = jax.random.uniform(jax.random.PRNGKey(4), (10, 1))
     rows = jnp.arange(4, dtype=jnp.int32)
     out = ops.gather_dequant(q, scales, rows, dtype=jnp.bfloat16)

@@ -179,6 +179,55 @@ def test_resnet_forward():
         assert logits.shape == (2, 10) and bool(jnp.isfinite(logits).all())
 
 
+def test_resnet_imagenet_stem_forward():
+    """The published ImageNet configs use the 7x7 stride-2 conv + 3x3
+    stride-2 max-pool stem; the 32 px reduced config keeps the 3x3 stem."""
+    from repro.configs import resnet50_cl
+    from repro.models.resnet import apply_cnn, init_cnn
+
+    ccfg = resnet50_cl.full()
+    assert ccfg.stem == "imagenet" and resnet50_cl.reduced().stem == "cifar"
+    small = type(ccfg)(**{**ccfg.__dict__, "width": 8, "stage_blocks": (1, 1),
+                          "num_classes": 10})
+    params = init_cnn(jax.random.PRNGKey(0), small)
+    assert params["stem"].shape == (7, 7, 3, 8)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 64, 64, 3))
+    logits = apply_cnn(params, x, small)
+    assert logits.shape == (2, 10) and bool(jnp.isfinite(logits).all())
+
+
+@pytest.mark.parametrize("b,s,v,pieces", [(3, 64, 512, 4), (2, 32, 1000, 4),
+                                          (3, 64, 512, 1)])
+def test_chunked_cross_entropy_matches_one_block(b, s, v, pieces):
+    """The sequence-chunked CE of the decoder loss equals the one-block CE,
+    gradients included: bit for bit as one chunk (the size the decoder loss
+    uses below ``LOGITS_BLOCK_BYTES``), up to summation order in more."""
+    from repro.models.model_zoo import chunked_cross_entropy
+
+    key = jax.random.PRNGKey(0)
+    hidden = jax.random.normal(key, (b, s, 16))
+    table = jax.random.normal(jax.random.fold_in(key, 1), (v, 16))
+    labels = jax.random.randint(jax.random.fold_in(key, 2), (b, s), -1, v)
+
+    def one_block(t):
+        return cross_entropy(jnp.einsum("bsd,vd->bsv", hidden, t), labels)
+
+    def chunked(t):
+        return chunked_cross_entropy(
+            lambda h: jnp.einsum("bsd,vd->bsv", h, t), hidden, labels, s // pieces)
+
+    if pieces == 1:
+        assert float(chunked(table)) == float(one_block(table))
+        assert np.array_equal(np.asarray(jax.grad(chunked)(table)),
+                              np.asarray(jax.grad(one_block)(table)))
+        return
+    np.testing.assert_allclose(float(chunked(table)), float(one_block(table)),
+                               rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(jax.grad(chunked)(table)),
+                               np.asarray(jax.grad(one_block)(table)),
+                               rtol=1e-4, atol=1e-6)
+
+
 def test_scan_vs_unroll_equivalence():
     """scan_layers=False (dry-run unrolled path) is numerically identical."""
     cfg = get_reduced("jamba-v0.1-52b")

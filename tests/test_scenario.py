@@ -213,6 +213,27 @@ def test_domain_stream_shares_label_space():
 # ---------------------------------------------------------------------------
 
 
+def test_chunked_vision_eval_equals_whole_set():
+    """An eval set larger than one chunk is evaluated in fixed-size chunks
+    (the last zero-padded) and gives the whole-set top-1 accuracy."""
+    from repro.core import topk_accuracy
+    from repro.models.resnet import apply_cnn
+    from repro.scenario.scenarios import EVAL_CHUNK
+
+    sc = ClassIncremental(ScenarioConfig(num_tasks=2, classes_per_task=20,
+                                         image_size=8))
+    ev = sc.eval_set(1)
+    assert len(ev["label"]) > EVAL_CHUNK and len(ev["label"]) % EVAL_CHUNK
+    assert sc.eval_set(1) is ev  # generated once per task
+    ccfg = resnet50_cl.reduced(num_classes=sc.num_classes)
+    run = RunConfig(model=ccfg, train=TrainConfig(compute_dtype="float32"))
+    problem = sc.build_problem(run)
+    params = problem.init_params_fn(jax.random.PRNGKey(3))
+    whole = float(topk_accuracy(apply_cnn(params, jnp.asarray(ev["images"]), ccfg),
+                                jnp.asarray(ev["label"]), k=1))
+    assert problem.eval_fn(params, 1) == whole
+
+
 def test_scenario_policy_default_selection():
     ci = get_scenario(ScenarioConfig(num_tasks=3, classes_per_task=2))
     dom = get_scenario(ScenarioConfig(name="domain_incremental", num_tasks=3,
@@ -373,6 +394,9 @@ def test_pjit_backend_matches_carry_fingerprints(tiering):
     if tiering == "host":
         # the tiered run really exceeded hot capacity at some point
         assert max(fill for _, fill in pj) > 2 * 4
+        # the carry backend keeps both tiers in device memory and says so
+        assert carry_res.step_meta == {"tiering": "host",
+                                       "cold_placement": "device"}
 
 
 def test_pjit_tiered_step_builder_no_longer_raises():
@@ -428,11 +452,12 @@ def test_rehearsal_buffer_cost_models_cold_tier():
     tier = rehearsal_buffer_cost(
         built, RehearsalConfig(num_buckets=4, mode="async", tiering="host",
                                hot_slots=16, cold_slots=48))
-    # the RESOLVED placement is surfaced: a tiered config whose cold tier fell
-    # back to device residency (CPU: no pinned_host) must be visible
+    # the placement is decided by platform: the CPU keeps the cold tier in
+    # device memory (pinned_host on an accelerator)
     assert tier["cold_placement"] == "device"  # CPU test runner
-    # cold rows: int leaves raw (128*4B) + float leaves int8 + 4B scale
-    assert tier["cold_host_bytes"] == 4 * 48 * (128 * 4 + 64 + 4)
+    # cold rows: int leaves raw (128*4B) + float leaves as one 128-lane int8
+    # row (64 elements padded to 128) + 4B scale
+    assert tier["cold_host_bytes"] == 4 * 48 * (128 * 4 + 128 + 4)
     assert tier["capacity_multiplier"] == 4.0
     assert tier["hot_hbm_bytes"] > flat["hot_hbm_bytes"]  # demotion staging rows
     off = rehearsal_buffer_cost(

@@ -37,7 +37,8 @@ def test_init_shapes_and_config_resolution():
                        stage_rows=8)
     assert B.tiered_dims(st) == (2, 4, 12)
     assert st.hot.data["x"].shape == (2, 4, 8)
-    assert st.cold.data["x"]["q"].shape == (2, 12, 8)  # int8 rows
+    # int8 rows, each one 128-lane slab (8 elements zero-padded to 128)
+    assert st.cold.data["x"]["q"].shape == (2, 12, 1, 128)
     assert st.cold.data["x"]["q"].dtype == jnp.int8
     assert st.cold.data["label"]["raw"].shape == (2, 12)  # ints pass through
     assert st.stage["x"].shape == (8, 8)
@@ -203,7 +204,7 @@ def _cold_rows(counts, q):
     rows = set()
     for idx in np.ndindex(*counts.shape):
         for j in range(int(counts[idx])):
-            rows.add(tuple(q[idx + (j,)].tolist()))
+            rows.add(tuple(np.ravel(q[idx + (j,)]).tolist()))
     return rows
 
 
