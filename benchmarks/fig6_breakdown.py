@@ -378,19 +378,22 @@ def _obs_overhead(h, rcfg, params, key, n=30, trials=3):
 def _chaos_obs(h, params, key, out_dir="obs_fig6", smoke=False):
     """Chaos run under full telemetry; validates the emitted artifacts.
 
-    A tiered ``PhasePipeline`` (all four phase spans) steps inside a
-    ``ResilientLoop`` whose failure hook kills step 2 once (≥1 restart event +
-    restore span), then a 2-worker tiered carry is scaled down through
-    ``scale_carry`` (≥1 reshard event + span). The resulting ``trace.json``
-    must validate against the Chrome trace-event schema and ``events.jsonl``
-    must carry the restart and reshard kinds — the acceptance contract for the
-    telemetry layer, enforced here so CI reruns it on every benchmark pass."""
+    The tiered fused step (``make_cl_step``) steps inside a ``ResilientLoop``
+    whose failure hook kills step 2 once (≥1 restart event + restore span),
+    then a 2-worker tiered carry is scaled down through ``scale_carry`` (≥1
+    reshard event + span). The compiled step's HLO must carry the named
+    scope of each of its stages (``repro.obs.scopes``), the resulting
+    ``trace.json`` must validate against the Chrome trace-event schema and
+    ``events.jsonl`` must carry the restart and reshard kinds — the
+    acceptance contract for the telemetry layer, enforced here so CI reruns
+    it on every benchmark pass."""
     import shutil
 
     from repro import obs as obs_mod
     from repro.checkpoint import CheckpointManager
     from repro.configs.base import ObsConfig, RehearsalConfig
     from repro.obs import read_events, validate_trace
+    from repro.obs.scopes import scopes_in_hlo
     from repro.runtime.autoscale import scale_carry
     from repro.runtime.fault_tolerance import InjectedFailure, ResilientLoop
 
@@ -402,13 +405,13 @@ def _chaos_obs(h, params, key, out_dir="obs_fig6", smoke=False):
                                num_representatives=4, num_candidates=8,
                                mode="async", tiering="host", hot_slots=8,
                                cold_slots=16)
-        pipeline = obs_mod.PhasePipeline(
-            h.loss_fn, h.opt_update, rcfg, exchange="local",
-            label_field="label", obs=ObsConfig(enabled=True))
+        step = make_cl_step(h.loss_fn, h.opt_update, rcfg, strategy="rehearsal",
+                            exchange="local", label_field="label", donate=False,
+                            obs=ObsConfig(enabled=True))
         carry = init_carry(params, h.opt_init(params), h.item_spec, rcfg,
                            label_field="label")
         loop = ResilientLoop(
-            step_fn=pipeline.step,
+            step_fn=step,
             ckpt=CheckpointManager(os.path.join(out_dir, "ckpt")),
             checkpoint_every=2, max_restarts=2, backoff_base=0.0)
         fired = []
@@ -422,6 +425,19 @@ def _chaos_obs(h, params, key, out_dir="obs_fig6", smoke=False):
             return {k: jnp.asarray(v) for k, v in
                     h.stream.batch(0, h.batch_size, s).items()}
 
+        # the stages are named inside the one fused program (no sanitizer
+        # wrapper around this build: it is lowered, never run)
+        hlo = make_cl_step(
+            h.loss_fn, h.opt_update, rcfg, strategy="rehearsal",
+            exchange="local", label_field="label", donate=False,
+            sanitize=False).lower(carry, batch_fn(0), key).compile().as_text()
+        # one device, local draw: every stage but the exchange
+        missing = ({"train", "optimizer", "buffer_update", "buffer_sample",
+                    "augment"} - scopes_in_hlo(hlo))
+        if missing:
+            raise RuntimeError(f"compiled chaos step missing step scopes: "
+                               f"{sorted(missing)}")
+
         carry, _, restarts = loop.run(carry, batch_fn, key, steps,
                                       failure_hook=chaos)
 
@@ -431,10 +447,6 @@ def _chaos_obs(h, params, key, out_dir="obs_fig6", smoke=False):
         _, reshard_s = scale_carry(dist, 1)
 
         tracer, bus = obs_mod.get_tracer(), obs_mod.get_event_bus()
-        missing = set(obs_mod.PHASES) - tracer.span_names()
-        if missing:
-            raise RuntimeError(f"chaos trace missing pipeline spans: "
-                               f"{sorted(missing)}")
         for kind in ("restart", "reshard", "checkpoint_save",
                      "checkpoint_restore"):
             if kind not in bus.kinds():

@@ -24,6 +24,7 @@ from repro.buffer.tiered import (
     tiered_sample,
     tiered_update,
 )
+from repro.obs.scopes import scope
 
 AnyBufferState = Union[BufferState, TieredState]
 
@@ -50,20 +51,22 @@ def buffer_update(state: AnyBufferState, items, labels, key, rcfg, *,
     """Policy-driven Alg-1 push of a candidate mini-batch into either store.
     ``cold_host``: a tiered store's cold records live in host memory."""
     pol = _policy_of(rcfg)
-    if isinstance(state, TieredState):
-        return tiered_update(state, items, labels, key, rcfg.num_candidates, pol,
-                             fused=_fused_of(rcfg), cold_host=cold_host)
-    return local_update(state, items, labels, key, rcfg.num_candidates, pol)
+    with scope("buffer_update"):
+        if isinstance(state, TieredState):
+            return tiered_update(state, items, labels, key, rcfg.num_candidates,
+                                 pol, fused=_fused_of(rcfg), cold_host=cold_host)
+        return local_update(state, items, labels, key, rcfg.num_candidates, pol)
 
 
 def buffer_sample(state: AnyBufferState, key, n: int, rcfg=None, *,
                   cold_host: bool = False):
     """Draw ``n`` representatives from either store under the configured policy."""
     pol = _policy_of(rcfg)
-    if isinstance(state, TieredState):
-        return tiered_sample(state, key, n, pol, fused=_fused_of(rcfg),
-                             cold_host=cold_host)
-    return local_sample(state, key, n, pol)
+    with scope("buffer_sample"):
+        if isinstance(state, TieredState):
+            return tiered_sample(state, key, n, pol, fused=_fused_of(rcfg),
+                                 cold_host=cold_host)
+        return local_sample(state, key, n, pol)
 
 
 def buffer_fill(state: AnyBufferState) -> jnp.ndarray:

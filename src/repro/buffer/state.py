@@ -22,6 +22,8 @@ from typing import Any, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs.scopes import scope
+
 
 class BufferState(NamedTuple):
     """Per-worker rehearsal buffer B_n (a pytree: ``data`` leaves are [K, slots, ...]).
@@ -218,7 +220,8 @@ def augment_batch(batch, reps, valid, label_field: str = "labels"):
     the first iteration) contribute zero loss via label masking, preserving static
     shapes.
     """
-    reps = mask_invalid(reps, valid, label_field)
-    return jax.tree_util.tree_map(
-        lambda a, b_: jnp.concatenate([a, b_.astype(a.dtype)], axis=0), batch, reps
-    )
+    with scope("augment"):
+        reps = mask_invalid(reps, valid, label_field)
+        return jax.tree_util.tree_map(
+            lambda a, b_: jnp.concatenate([a, b_.astype(a.dtype)], axis=0), batch, reps
+        )

@@ -180,8 +180,8 @@ def tiered_flush(state: TieredState, key, *, fused: bool = False,
                  cold_host: bool = False) -> TieredState:
     """Flush the pending demotions (staged at step t−1) into the cold archive:
     one batched int8 encode + reservoir insert. Clears ``stage_valid`` so a
-    standalone flush (the phase-decomposed form, repro.obs.pipeline) cannot
-    re-demote the same rows; ``tiered_update`` overwrites the stage anyway.
+    standalone flush cannot re-demote the same rows; ``tiered_update``
+    overwrites the stage anyway.
 
     ``fused=True`` routes through the encode-on-scatter Pallas kernel
     (``compression.encode_scatter_batch``): the staged rows are quantized and

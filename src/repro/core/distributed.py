@@ -32,6 +32,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.buffer import api as buffer_api
 from repro.core import rehearsal as rb
+from repro.obs.scopes import scope
 from repro.utils.compat import shard_map
 
 
@@ -57,11 +58,14 @@ def _exchange(items, valid, axis_names):
 
     Deterministic collective — takes no PRNG key. (It used to accept the
     already-consumed ``k_draw`` and ignore it, a replint RPL001 finding.)"""
-    recv = jax.tree_util.tree_map(
-        lambda x: jax.lax.all_to_all(x, axis_names, split_axis=0, concat_axis=0, tiled=True),
-        items,
-    )
-    recv_valid = jax.lax.all_to_all(valid, axis_names, split_axis=0, concat_axis=0, tiled=True)
+    with scope("exchange"):
+        recv = jax.tree_util.tree_map(
+            lambda x: jax.lax.all_to_all(x, axis_names, split_axis=0, concat_axis=0,
+                                         tiled=True),
+            items,
+        )
+        recv_valid = jax.lax.all_to_all(valid, axis_names, split_axis=0,
+                                        concat_axis=0, tiled=True)
     return recv, recv_valid
 
 
@@ -256,16 +260,16 @@ def augment_global(batch, reps, valid, n_dp: int, label_field: str = "labels"):
     Invalid representatives get their ``label_field`` masked to -1 here, mirroring
     the single-device ``augment_batch`` (idempotent when the producer already
     masked them via ``consume_reps``, as ``make_sharded_update`` does)."""
-    flat = jax.tree_util.tree_map(lambda x: x.reshape((-1,) + x.shape[2:]), reps)
-    flat = rb.mask_invalid(flat, valid.reshape(-1), label_field)
-    reps = jax.tree_util.tree_map(
-        lambda x, ref: x.reshape(ref.shape), flat, reps
-    )
-
     def cat(b_leaf, r_leaf):
         bg = b_leaf.shape[0]
         b2 = b_leaf.reshape((n_dp, bg // n_dp) + b_leaf.shape[1:])
         out = jnp.concatenate([b2, r_leaf.astype(b_leaf.dtype)], axis=1)
         return out.reshape((bg + n_dp * r_leaf.shape[1],) + b_leaf.shape[1:])
 
-    return jax.tree_util.tree_map(cat, batch, reps)
+    with scope("augment"):
+        flat = jax.tree_util.tree_map(lambda x: x.reshape((-1,) + x.shape[2:]), reps)
+        flat = rb.mask_invalid(flat, valid.reshape(-1), label_field)
+        reps = jax.tree_util.tree_map(
+            lambda x, ref: x.reshape(ref.shape), flat, reps
+        )
+        return jax.tree_util.tree_map(cat, batch, reps)

@@ -9,11 +9,16 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, Optional
 
 import jax
 import numpy as np
+
+from repro.obs.trace import get_tracer
+
+INPUT_TID = 2  # the prefetch thread's track in the Tracer (0 main, 1 checkpoint)
 
 
 @dataclass
@@ -48,6 +53,17 @@ class Prefetcher:
     ``convert`` (e.g. ``jnp.asarray``) is applied to every batch leaf on the
     background thread, so host→device conversion overlaps training instead of
     sitting on the critical path (the trainer's Load stage, paper §V).
+
+    Spans (``repro.obs.trace``, on any profiler trace's host plane):
+    ``input.fetch`` (the ``fetch`` call) and ``input.convert`` (the
+    ``convert`` call) on the thread that runs them, ``input.wait`` (``next()``
+    blocked on the queue) and ``input.place`` (the placement on ``sharding``
+    in ``next()``). ``counters()`` reads four always-on counters, cumulative
+    over the prefetcher's life: ``batches`` handed out, ``not_ready`` (of
+    those, batches with a leaf whose host-to-device copy was still in flight,
+    a non-blocking check), ``convert_s`` and ``wait_s`` (host seconds in
+    ``input.convert`` and ``input.wait``). Each counter has one writer
+    thread: ``convert_s`` the thread that converts, the others ``next()``'s.
     """
 
     def __init__(self, fetch: Callable[[Cursor], Dict[str, np.ndarray]],
@@ -65,13 +81,36 @@ class Prefetcher:
         self._thread: Optional[threading.Thread] = None
         self._exhausted = False  # worker hit _limit and enqueued _EndOfStream
         self._served = 0  # batches handed out by next(), either path: ONE limit
+        self._counts = {"batches": 0, "not_ready": 0, "convert_s": 0.0,
+                        "wait_s": 0.0}
 
-    def _place(self, batch):
-        if self._sharding is None:
-            return batch
-        return jax.tree_util.tree_map(
-            lambda x, s: jax.device_put(x, s), batch, self._sharding
-        )
+    def counters(self) -> Dict[str, float]:
+        """A snapshot of the input counters (see the class docstring)."""
+        return dict(self._counts)
+
+    def _produce(self, cur: Cursor, tid: int):
+        """fetch + convert of one batch, under the input spans."""
+        tracer = get_tracer()
+        with tracer.span("input.fetch", cat="input", tid=tid):
+            batch = self._fetch(cur)
+        if self._convert is not None:
+            t0 = time.perf_counter()
+            with tracer.span("input.convert", cat="input", tid=tid):
+                batch = {k: self._convert(v) for k, v in batch.items()}
+            self._counts["convert_s"] += time.perf_counter() - t0
+        return batch
+
+    def _hand_out(self, batch):
+        """Place the batch on ``sharding`` and count it."""
+        if self._sharding is not None:
+            with get_tracer().span("input.place", cat="input"):
+                batch = jax.tree_util.tree_map(jax.device_put, batch,
+                                               self._sharding)
+        self._counts["batches"] += 1
+        if any(isinstance(x, jax.Array) and not x.is_ready()
+               for x in jax.tree_util.tree_leaves(batch)):
+            self._counts["not_ready"] += 1
+        return batch
 
     def _worker(self, start: Cursor):
         cur = Cursor(start.task, start.step)
@@ -83,9 +122,7 @@ class Prefetcher:
                 self._enqueue((None, _EndOfStream()))
                 return
             try:
-                batch = self._fetch(cur)
-                if self._convert is not None:
-                    batch = {k: self._convert(v) for k, v in batch.items()}
+                batch = self._produce(cur, INPUT_TID)
             except BaseException as e:  # surface in next(), don't hang the consumer
                 batch = _FetchError(e)
             self._enqueue((Cursor(cur.task, cur.step), batch))
@@ -118,14 +155,15 @@ class Prefetcher:
                                and self._served >= self._limit):
             raise StopIteration(f"prefetch limit ({self._limit}) reached")
         if self._thread is None:  # synchronous fallback
-            batch = self._fetch(self.cursor)
-            if self._convert is not None:
-                batch = {k: self._convert(v) for k, v in batch.items()}
+            batch = self._produce(self.cursor, 0)
             cur = Cursor(self.cursor.task, self.cursor.step)
             self.cursor.step += 1
             self._served += 1
-            return cur, self._place(batch)
-        cur, batch = self._q.get()
+            return cur, self._hand_out(batch)
+        t0 = time.perf_counter()
+        with get_tracer().span("input.wait", cat="input"):
+            cur, batch = self._q.get()
+        self._counts["wait_s"] += time.perf_counter() - t0
         if isinstance(batch, _EndOfStream):
             # the producer exited after its last allowed fetch; reclaim the
             # (already finished) thread and report exhaustion, not a hang
@@ -139,7 +177,7 @@ class Prefetcher:
             raise batch.exc
         self.cursor = Cursor(cur.task, cur.step + 1)
         self._served += 1
-        return cur, self._place(batch)
+        return cur, self._hand_out(batch)
 
     def stop(self):
         self._stop.set()

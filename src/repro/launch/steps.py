@@ -39,6 +39,7 @@ from repro.core import distributed as dist
 from repro.core import rehearsal as rb
 from repro.strategy import outputs_row_spec, rep_checksum, resolve_strategy
 from repro.models import StackCtx, build_model
+from repro.obs.scopes import scope
 from repro.optim import make_optimizer
 from repro.parallel import (
     batch_shardings,
@@ -249,13 +250,19 @@ def build_train_step(
     def loss_of(params, batch):
         return model.loss(tree_cast(params, compute_dtype), batch, ctx)
 
-    grad_fn = jax.value_and_grad(loss_of, has_aux=True)
+    def grad_fn(params, batch):
+        with scope("train"):
+            return jax.value_and_grad(loss_of, has_aux=True)(params, batch)
+
+    def opt_step(grads, opt_state, params):
+        with scope("optimizer"):
+            return opt_update(grads, opt_state, params)
 
     if not use_rehearsal:
 
         def step(params, opt_state, batch, key):
             (loss, metrics), grads = grad_fn(params, batch)
-            params, opt_state, om = opt_update(grads, opt_state, params)
+            params, opt_state, om = opt_step(grads, opt_state, params)
             metrics = dict(metrics, **om, loss=loss)
             if obs_on:
                 metrics.update(obs_step_metrics(grads=grads, params=params,
@@ -279,7 +286,7 @@ def build_train_step(
             aug = dist.augment_global(batch, new_reps, new_valid, n_dp,
                                       rcfg.label_field)
             (loss, metrics), grads = grad_fn(params, aug)
-            params, opt_state, om = opt_update(grads, opt_state, params)
+            params, opt_state, om = opt_step(grads, opt_state, params)
             fingerprints = {
                 "buffer_fill": buffer_api.buffer_fill(buffer).astype(jnp.float32),
                 "rep_checksum": rep_checksum(new_reps, new_valid, rcfg.label_field),
@@ -295,7 +302,11 @@ def build_train_step(
     elif tap:  # pipelined tap strategy: DER(++) / grasp_embed (DESIGN.md §9)
         tap_loss = strat.build_loss(None, outputs_of, scfg,
                                     label_field=rcfg.label_field)
-        grad_tap = jax.value_and_grad(tap_loss, has_aux=True)
+
+        def grad_tap(params, batch):
+            with scope("train"):
+                return jax.value_and_grad(tap_loss, has_aux=True)(params, batch)
+
         bg = shape.global_batch
 
         def step(params, opt_state, buffer, reps, valid, batch, key):
@@ -318,7 +329,7 @@ def build_train_step(
             buffer, next_reps, next_valid = sharded_update(
                 buffer, store, batch[task_field], key
             )
-            params, opt_state, om = opt_update(grads, opt_state, params)
+            params, opt_state, om = opt_step(grads, opt_state, params)
             fingerprints = {
                 "buffer_fill": buffer_api.buffer_fill(buffer).astype(jnp.float32),
                 "rep_checksum": rep_checksum(reps, valid, rcfg.label_field),
@@ -345,7 +356,7 @@ def build_train_step(
             buffer, next_reps, next_valid = sharded_update(
                 buffer, batch, batch[task_field], key
             )
-            params, opt_state, om = opt_update(grads, opt_state, params)
+            params, opt_state, om = opt_step(grads, opt_state, params)
             fingerprints = {
                 "buffer_fill": buffer_api.buffer_fill(buffer).astype(jnp.float32),
                 "rep_checksum": rep_checksum(reps, valid, rcfg.label_field),

@@ -4,9 +4,10 @@ Four pieces, one switch (``RunConfig.obs`` / ``ObsConfig``):
 
 * jit-safe step metrics (``repro.obs.metrics``) — ``obs/*`` f32 scalars merged
   into the step factories' metrics output; zero fingerprint/RNG impact;
-* trace spans (``repro.obs.trace``) — Chrome/Perfetto ``trace.json``;
-  ``repro.obs.pipeline.PhasePipeline`` decomposes the pipelined step so the
-  four phases get real host-bounded spans;
+* trace spans (``repro.obs.trace``) — Chrome/Perfetto ``trace.json``, each
+  span also on the host plane of any profiler trace; the fused step's stages
+  carry named scopes instead (``repro.obs.scopes``), which a profiler trace
+  sums by stage;
 * runtime event log (``repro.obs.events``) — one ``EventBus``, ``events.jsonl``;
 * exporters (``repro.obs.exporters``) — Prometheus text endpoint +
   ``MetricsWriter`` folding step metrics into fit() history / BENCH payloads.
@@ -32,6 +33,7 @@ from repro.obs.exporters import (
     start_metrics_server,
 )
 from repro.obs.metrics import estimate_obs_cost, obs_keys, step_metrics
+from repro.obs.scopes import STEP_SCOPES, scope_of
 from repro.obs.trace import Tracer, get_tracer, set_tracer, validate_trace
 
 _STATE = {"dir": None}
@@ -75,22 +77,9 @@ def shutdown() -> Optional[str]:
     return path
 
 
-def __getattr__(name):
-    # PhasePipeline imports strategy.step, which imports repro.obs.metrics —
-    # resolving it lazily keeps this package import-light and cycle-free.
-    if name == "PhasePipeline":
-        from repro.obs.pipeline import PhasePipeline
-        return PhasePipeline
-    if name == "PHASES":
-        from repro.obs.pipeline import PHASES
-        return PHASES
-    raise AttributeError(f"module 'repro.obs' has no attribute {name!r}")
-
-
 __all__ = [
-    "EventBus", "MetricsRegistry", "MetricsWriter", "PHASES", "PhasePipeline",
-    "Tracer", "configure", "estimate_obs_cost", "exporters", "flush",
+    "EventBus", "MetricsRegistry", "MetricsWriter", "STEP_SCOPES", "Tracer", "configure", "estimate_obs_cost", "exporters", "flush",
     "get_event_bus", "get_tracer", "metrics", "obs_keys", "read_events",
-    "set_event_bus", "set_tracer", "shutdown", "start_metrics_server",
-    "step_metrics", "validate_trace",
+    "scope_of", "set_event_bus", "set_tracer", "shutdown",
+    "start_metrics_server", "step_metrics", "validate_trace",
 ]

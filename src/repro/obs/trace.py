@@ -8,17 +8,19 @@ Perfetto / ``chrome://tracing`` load directly::
                       "pid": <rank>, "tid": <track>, ...}, ...],
      "displayTimeUnit": "ms"}
 
-Spans are *host-side*: they time dispatch→blocked completion of separately
-dispatched device programs (``repro.obs.pipeline`` decomposes the pipelined
-step into its four phases for exactly this), checkpoint save/restore, reshard
-and autoscale decisions. Inside one fused jitted program host timestamps are
-meaningless — that cost breakdown is the benchmarks' job, not the tracer's.
+Spans are *host-side*: they time host work — the input thread
+(``input.fetch``/``input.convert``/``input.wait``/``input.place``), eval,
+checkpoint save/restore, reshard and autoscale decisions. Every span is also a
+``jax.profiler.TraceAnnotation``, so a profiler trace shows it on its host
+plane beside the device's ops, on one clock. Inside one fused jitted program
+host timestamps are meaningless: the step's stages carry named scopes instead
+(``repro.obs.scopes``), which a profiler trace sums by stage.
 
 Per-rank tracks: ``pid`` defaults to the ``REPRO_MP_PID`` rank of
 ``runtime/multiproc.py`` (0 single-process), so an N-process mesh writing one
 trace file per rank merges into N labelled process tracks in Perfetto. ``tid``
 separates host threads within a rank (0 = main loop, 1 = the checkpoint
-writer's async thread).
+writer's async thread, 2 = the ``Prefetcher``'s input thread).
 
 The module-global tracer starts *disabled* (every call is a cheap no-op);
 ``repro.obs.configure`` swaps in a live one.
@@ -31,6 +33,8 @@ import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+import jax
 
 _REQUIRED_PHASE_FIELDS = {"name", "ph", "ts", "pid", "tid"}
 
@@ -61,20 +65,24 @@ class Tracer:
             self._events.append(ev)
 
     @contextlib.contextmanager
-    def span(self, name: str, cat: str = "pipeline", tid: int = 0, **args):
-        """Time a ``with`` block as one complete ('X') span."""
-        if not self.enabled:
-            yield
-            return
-        t0 = self._now_us()
-        try:
-            yield
-        finally:
-            ev = {"name": name, "cat": cat, "ph": "X", "ts": t0,
-                  "dur": self._now_us() - t0, "pid": self.pid, "tid": tid}
-            if args:
-                ev["args"] = dict(args)
-            self._append(ev)
+    def span(self, name: str, cat: str = "host", tid: int = 0, **args):
+        """Time a ``with`` block as one complete ('X') span. The block is also
+        a ``jax.profiler.TraceAnnotation`` of the same name, enabled or not, so
+        it lands on the host plane of any profiler trace, on the clock of the
+        device's ops (close to free while no profiler session is active)."""
+        with jax.profiler.TraceAnnotation(name):
+            if not self.enabled:
+                yield
+                return
+            t0 = self._now_us()
+            try:
+                yield
+            finally:
+                ev = {"name": name, "cat": cat, "ph": "X", "ts": t0,
+                      "dur": self._now_us() - t0, "pid": self.pid, "tid": tid}
+                if args:
+                    ev["args"] = dict(args)
+                self._append(ev)
 
     def instant(self, name: str, cat: str = "event", tid: int = 0, **args):
         if not self.enabled:

@@ -18,6 +18,8 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs.scopes import scope
+
 
 def init_error_feedback(params):
     return jax.tree_util.tree_map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
@@ -50,13 +52,15 @@ def compressed_psum(grads, axis_name, ef_state, n_workers: int):
 
     flat_g, treedef = jax.tree_util.tree_flatten(grads)
     flat_e = treedef.flatten_up_to(ef_state)
-    out = [one(g, e) for g, e in zip(flat_g, flat_e)]
+    with scope("grad_allreduce"):
+        out = [one(g, e) for g, e in zip(flat_g, flat_e)]
     means = treedef.unflatten([m for m, _ in out])
     errs = treedef.unflatten([e for _, e in out])
     return means, errs
 
 
 def plain_psum(grads, axis_name, n_workers: int):
-    return jax.tree_util.tree_map(
-        lambda g: jax.lax.psum(g.astype(jnp.float32), axis_name) / n_workers, grads
-    )
+    with scope("grad_allreduce"):
+        return jax.tree_util.tree_map(
+            lambda g: jax.lax.psum(g.astype(jnp.float32), axis_name) / n_workers,
+            grads)

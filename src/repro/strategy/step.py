@@ -37,6 +37,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.buffer import api as buffer_api
 from repro.buffer import state as rb
+from repro.obs.scopes import scope
 from repro.optim.grad_compress import compressed_psum, plain_psum
 from repro.strategy.base import STRATEGIES, resolve_strategy
 from repro.utils.compat import shard_map
@@ -243,8 +244,9 @@ def make_cl_step(
             train_batch = dict(train_batch, is_replay=jnp.concatenate(
                 [jnp.zeros((b,), jnp.float32),
                  train_valid.astype(jnp.float32)]))
-            (loss, (aux_metrics, outs)), grads = jax.value_and_grad(
-                tap_loss, has_aux=True)(carry.params, train_batch)
+            with scope("train"):
+                (loss, (aux_metrics, outs)), grads = jax.value_and_grad(
+                    tap_loss, has_aux=True)(carry.params, train_batch)
             # store the new rows with their aux values (this step's outputs);
             # no dependency on the gradient subgraph — the exchange still
             # overlaps the backward pass
@@ -286,9 +288,9 @@ def make_cl_step(
             else:
                 train_batch = batch
 
-            (loss, aux_metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                carry.params, train_batch
-            )
+            with scope("train"):
+                (loss, aux_metrics), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(carry.params, train_batch)
         ef = carry.ef
         if axis is not None:
             if compress == "int8":
@@ -296,7 +298,8 @@ def make_cl_step(
             else:
                 grads = plain_psum(grads, axis, n_workers)
             loss = jax.lax.pmean(loss, axis)
-        params, opt, opt_metrics = opt_update(grads, carry.opt, carry.params)
+        with scope("optimizer"):
+            params, opt, opt_metrics = opt_update(grads, carry.opt, carry.params)
         metrics.update(loss=loss, **aux_metrics, **opt_metrics)
         if obs_on:
             from repro.obs.metrics import step_metrics as obs_step_metrics
@@ -417,10 +420,12 @@ def make_stale_step(
             dist.PendingSample(pipe.reps, pipe.valid), label_field
         )
         train_batch = rb.augment_batch(batch, train_reps, train_valid, label_field)
-        (loss, aux_metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            carry.params, train_batch
-        )
-        params, opt, opt_metrics = opt_update(grads, carry.opt, carry.params)
+        with scope("train"):
+            (loss, aux_metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+                carry.params, train_batch
+            )
+        with scope("optimizer"):
+            params, opt, opt_metrics = opt_update(grads, carry.opt, carry.params)
         metrics = dict(
             aux_metrics, **opt_metrics, loss=loss, stale_step=jnp.float32(1.0),
             buffer_fill=buffer_api.buffer_fill(carry.buffer).astype(jnp.float32),
@@ -478,7 +483,7 @@ def make_pipelined_halves(
 
     ``obs`` merges the grad/param-norm + replay ``obs/*`` metrics into the
     train half's output (buffer gauges need the buffer and belong to the fused
-    form / ``repro.obs.pipeline``); the issue half's signature is unchanged.
+    form); the issue half's signature is unchanged.
     """
     from repro.core import distributed as dist
 
@@ -492,8 +497,11 @@ def make_pipelined_halves(
             dist.PendingSample(pipe.reps, pipe.valid), label_field
         )
         train_batch = rb.augment_batch(batch, train_reps, train_valid, label_field)
-        (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, train_batch)
-        params, opt, om = opt_update(grads, opt, params)
+        with scope("train"):
+            (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+                params, train_batch)
+        with scope("optimizer"):
+            params, opt, om = opt_update(grads, opt, params)
         metrics = dict(aux, **om, loss=loss)
         if obs_on:
             from repro.obs.metrics import step_metrics as obs_step_metrics

@@ -130,6 +130,129 @@ def test_tracer_disabled_is_noop():
     assert tr.events() == []
 
 
+# ---------------------------------------------------------------------------
+# Input thread: Prefetcher spans + counters, on a profiler trace's host plane
+# ---------------------------------------------------------------------------
+
+
+@jax.jit
+def _slow_copy(x):
+    """A device computation that is still running when its call returns."""
+    return jax.lax.fori_loop(0, 500, lambda i, y: jnp.tanh(y @ y), x)
+
+
+def _input_batch(cur):
+    return {"x": np.full((128, 128), 0.01 * (cur.step + 1), np.float32)}
+
+
+def test_prefetcher_counts_batches_handed_out_in_flight():
+    from repro.data import Prefetcher
+
+    pf = Prefetcher(_input_batch, convert=lambda v: _slow_copy(jnp.asarray(v)))
+    _slow_copy(jnp.ones((128, 128))).block_until_ready()  # compiled ahead
+    _, batch = pf.next()  # synchronous: fetch, convert, hand out at once
+    c = pf.counters()
+    assert c["batches"] == 1 and c["not_ready"] == 1
+    assert c["convert_s"] > 0 and c["wait_s"] == 0
+    jax.block_until_ready(batch)
+    c["batches"] = 99  # a snapshot, not the live counters
+    assert pf.counters()["batches"] == 1
+
+
+def test_prefetcher_counts_ready_batches_and_waits():
+    from repro.data import Prefetcher
+
+    pf = Prefetcher(_input_batch,
+                    convert=lambda v: jax.block_until_ready(jnp.asarray(v))).start()
+    try:
+        for _ in range(3):
+            pf.next()
+    finally:
+        pf.stop()
+    c = pf.counters()
+    assert c["batches"] == 3 and c["not_ready"] == 0
+    assert c["convert_s"] > 0 and c["wait_s"] > 0
+
+
+def test_prefetcher_counts_numpy_batches_as_ready():
+    from repro.data import Prefetcher
+
+    pf = Prefetcher(_input_batch)
+    pf.next()
+    pf.next()
+    assert pf.counters() == {"batches": 2, "not_ready": 0, "convert_s": 0.0,
+                             "wait_s": 0.0}
+
+
+def test_prefetcher_places_on_a_sharding_under_its_own_span():
+    """Placement on ``sharding`` runs in ``next()`` under ``input.place``;
+    ``input.convert`` and ``convert_s`` hold the input thread's ``convert``
+    call alone."""
+    from repro.data import Prefetcher
+    from repro.obs.trace import get_tracer, set_tracer
+
+    sh = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    old, tr = get_tracer(), set_tracer(Tracer(enabled=True))
+    try:
+        pf = Prefetcher(_input_batch, sharding={"x": sh},
+                        convert=lambda v: v + 1).start()
+        try:
+            for _ in range(3):
+                _, b = pf.next()
+                assert isinstance(b["x"], jax.Array) and b["x"].sharding == sh
+        finally:
+            pf.stop()
+    finally:
+        set_tracer(old)
+    threads = {}
+    for e in tr.events():
+        if e["name"].startswith("input."):
+            threads.setdefault(e["name"], set()).add(e["tid"])
+    assert threads["input.place"] == {0} and threads["input.wait"] == {0}
+    assert threads["input.convert"] == {2} and threads["input.fetch"] == {2}
+    assert tr.span_stats()["input.place"]["count"] == 3
+    assert pf.counters()["batches"] == 3 and pf.counters()["convert_s"] > 0
+
+
+def test_program_spans_land_on_the_profiler_host_plane(tmp_path):
+    """With the module-global tracer disabled, the input spans still reach a
+    profiler trace's host plane beside the XLA op events of the CPU client,
+    on one clock; the tracer itself records nothing."""
+    from jax.profiler import ProfileData
+
+    from repro.data import Prefetcher
+
+    step = jax.jit(lambda x: jnp.tanh(x @ x))
+    step(jnp.ones((64, 64))).block_until_ready()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        pf = Prefetcher(lambda cur: {"x": np.ones((64, 64), np.float32)},
+                        convert=jnp.asarray).start()
+        for _ in range(3):
+            _, b = pf.next()
+            step(b["x"]).block_until_ready()
+        pf.stop()
+    finally:
+        jax.profiler.stop_trace()
+    assert obs_mod.get_tracer().events() == []
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    host, ops = {}, []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("input."):
+                    host.setdefault(e.name, []).append(e.start_ns)
+                elif "hlo_op" in dict(e.stats):
+                    ops.append(e.start_ns)
+    assert set(host) == {"input.fetch", "input.convert", "input.wait"}  # no sharding
+    assert ops
+    # one clock: the op events fall among the spans, not on another epoch
+    spans = [t for ts in host.values() for t in ts]
+    assert min(spans) < max(ops) and min(ops) < max(spans)
+
+
 def test_validate_trace_rejects_malformed():
     assert validate_trace([]) != []
     assert validate_trace({}) != []
@@ -375,48 +498,6 @@ def test_grad_norms_flag_gates_norm_gauges():
     h, _ = _run_steps(_rcfg(), ObsConfig(enabled=True, grad_norms=False))
     assert "obs/grad_norm" not in h[0] and "obs/param_norm" not in h[0]
     assert "obs/fill" in h[0]  # the cheap gauges stay
-
-
-# ---------------------------------------------------------------------------
-# PhasePipeline: bit-exact vs the fused step, one span per phase
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("tiering", ["off", "host"])
-def test_phase_pipeline_matches_fused_step(tiering):
-    kw = {} if tiering == "off" else dict(tiering="host", hot_slots=8,
-                                          cold_slots=16)
-    rcfg = _rcfg(**kw)
-    h_fused, c_fused = _run_steps(rcfg, None)
-
-    tracer = Tracer(enabled=True)
-    pipeline = obs_mod.PhasePipeline(_linear_loss, _sgd, rcfg,
-                                     exchange="local", label_field="label",
-                                     tracer=tracer)
-    params = {"w": jnp.zeros((8, 4))}
-    carry = init_carry(params, None, _spec(), rcfg, label_field="label", seed=3)
-    key = jax.random.PRNGKey(0)
-    losses = []
-    for s in range(6):
-        carry, m = pipeline.step(carry, _batch(s), jax.random.fold_in(key, s))
-        losses.append(np.asarray(m["loss"]))
-
-    for fused, phased in zip(h_fused, losses):
-        assert fused["loss"].tobytes() == phased.tobytes()
-    for a, b in zip(jax.tree_util.tree_leaves(c_fused.params),
-                    jax.tree_util.tree_leaves(carry.params)):
-        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
-    if tiering == "off":
-        assert np.asarray(c_fused.buffer.counts).tolist() == \
-            np.asarray(carry.buffer.counts).tolist()
-        expected = {"consume_reps", "issue_sample", "all_to_all"}
-    else:
-        assert np.asarray(c_fused.buffer.hot.counts).tolist() == \
-            np.asarray(carry.buffer.hot.counts).tolist()
-        assert np.asarray(c_fused.buffer.cold.counts).tolist() == \
-            np.asarray(carry.buffer.cold.counts).tolist()
-        expected = set(obs_mod.PHASES)
-    assert tracer.span_names() >= expected
 
 
 # ---------------------------------------------------------------------------
