@@ -61,6 +61,54 @@ def buffer_dims(state: BufferState) -> Tuple[int, int]:
     return leaf.shape[0], leaf.shape[1]  # (K, slots)
 
 
+def _row_start(buf, f):
+    cap = buf.shape[1]
+    return (f // cap, f % cap) + (0,) * (buf.ndim - 2)
+
+
+def read_rows(buf, flat):
+    """Flat rows ``flat`` (i32[n], in range) of a ``[K, slots, ...]`` leaf:
+    ``[n, ...]``, one dynamic slice per row.
+
+    Row moves of the buffer are dynamic slices, unrolled, never a gather or
+    scatter over the leaf reshaped to ``[K * slots, ...]`` and never a loop.
+    XLA:TPU stores a large leaf in the layout that packs it best, which can
+    put the slot axis minor-most (an f32[4, 2750, 224, 224, 3] leaf has its
+    slots innermost). A row gather or scatter cannot run in that layout, nor
+    can a loop that carries the leaf, so XLA then copies the whole leaf to
+    another layout and back; an unrolled dynamic slice runs in place in any
+    layout."""
+    shape = (1, 1) + buf.shape[2:]
+    if flat.shape[0] == 0:
+        return jnp.zeros((0,) + buf.shape[2:], buf.dtype)
+    return jnp.concatenate([jax.lax.dynamic_slice(buf, _row_start(buf, flat[i]), shape)[0]
+                            for i in range(flat.shape[0])])
+
+
+def write_rows(buf, flat, rows):
+    """``buf`` (``[K, slots, ...]``) with ``rows[i]`` written to flat row
+    ``flat[i]``, in place (see ``read_rows``): the semantics of
+    ``.at[flat].set(rows, mode="drop")`` on the flat view. Negative rows wrap,
+    rows outside ``[0, K * slots)`` are dropped, and where two rows share a
+    target the later one wins.
+
+    Every candidate makes one write. The dropped ones come first and write
+    flat row 0 back as it was, then the kept ones follow in order."""
+    total = buf.shape[0] * buf.shape[1]
+    f = jnp.where(flat < 0, flat + total, flat)
+    keep = (f >= 0) & (f < total)
+    order = jnp.argsort(keep, stable=True)
+    row0 = buf[:1, :1]
+    out = buf
+    for i in range(flat.shape[0]):
+        j, ok = order[i], keep[order[i]]
+        row = jax.lax.dynamic_index_in_dim(rows, j, keepdims=True)[None]
+        out = jax.lax.dynamic_update_slice(
+            out, jnp.where(ok, row.astype(buf.dtype), row0),
+            _row_start(buf, jnp.where(ok, f[j], 0)))
+    return out
+
+
 def local_update(
     state: BufferState, items, labels, key, num_candidates: int, policy=None,
     accept_mask=None,
@@ -152,18 +200,10 @@ def _local_update_traced(state, items, labels, key, num_candidates, policy=None,
     # it must not be reported (the within-batch occupant is simply dropped)
     evicted_valid = accept & (pos >= cap) & (slot < state.counts[labels])
 
-    def gather_old(buf):
-        flat_buf = buf.reshape((k_buckets * cap,) + buf.shape[2:])
-        return flat_buf[jnp.clip(flat, 0, k_buckets * cap - 1)]
-
-    evicted = jax.tree_util.tree_map(gather_old, state.data)
-
-    def scatter(buf, it):
-        flat_buf = buf.reshape((k_buckets * cap,) + buf.shape[2:])
-        out = flat_buf.at[flat].set(it.astype(buf.dtype), mode="drop")
-        return out.reshape(buf.shape)
-
-    new_data = jax.tree_util.tree_map(scatter, state.data, items)
+    safe = jnp.clip(flat, 0, k_buckets * cap - 1)
+    evicted = jax.tree_util.tree_map(lambda buf: read_rows(buf, safe), state.data)
+    new_data = jax.tree_util.tree_map(lambda buf, it: write_rows(buf, flat, it),
+                                      state.data, items)
     new_aux = pol.update_aux(state, items, labels, accept, flat, new_counts)
     return BufferState(new_data, new_counts, new_seen, new_aux), evicted, evicted_valid
 
@@ -191,13 +231,9 @@ def local_sample(state: BufferState, key, n: int, policy=None):
     (Drawn with replacement; for n ≪ |B_n| this matches the paper's
     without-replacement sampling to O(n/|B_n|).)
     """
-    k_buckets, cap = buffer_dims(state)
     flat, valid = local_sample_rows(state, key, n, policy)
 
-    def gather(buf):
-        return buf.reshape((k_buckets * cap,) + buf.shape[2:])[flat]
-
-    return jax.tree_util.tree_map(gather, state.data), valid
+    return jax.tree_util.tree_map(lambda buf: read_rows(buf, flat), state.data), valid
 
 
 def mask_invalid(items, valid, label_field: str = "labels"):

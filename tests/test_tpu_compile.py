@@ -185,3 +185,55 @@ def test_tiered_image_buffer_update_compiles_with_cold_tier_in_pinned_host(
     a, c = small.memory_analysis(), large.memory_analysis()
     device_bytes = lambda m: m.temp_size_in_bytes + m.argument_size_in_bytes
     assert device_bytes(c) - device_bytes(a) < table // 100
+
+
+def test_continual_step_moves_rows_of_a_large_image_buffer_in_place(one_chip):
+    """The one-device continual step over a flat buffer of 4 x 2750 ImageNet
+    records, built for a described v5e. The TPU's layout for that buffer puts
+    the slot axis minor-most (2750 pads better than 224). A row gather or
+    scatter over the buffer reshaped to rows cannot run in that layout, and
+    the step then copied the whole buffer to another layout and back inside
+    every call. The buffer's rows move by dynamic slices, which run in place:
+    no copy of the buffer is left, and it leaves the step in the layout it
+    came in. At 64 slots the buffer's layout already suits a row scatter, so
+    no small-slot test can see this."""
+    import re
+
+    from repro.configs.base import RehearsalConfig
+    from repro.core import init_carry, make_cl_step
+
+    k, slots, b = 4, 2750, 56
+    item_s = {"images": jax.ShapeDtypeStruct((224, 224, 3), jnp.float32),
+              "label": jax.ShapeDtypeStruct((), jnp.int32),
+              "task": jax.ShapeDtypeStruct((), jnp.int32)}
+    rcfg = RehearsalConfig(num_buckets=k, slots_per_bucket=slots,
+                           num_representatives=SAMPLED, num_candidates=14,
+                           mode="async", label_field="label", task_field="task")
+
+    def loss(params, batch):
+        x = batch["images"].reshape(batch["images"].shape[0], -1)
+        logp = jax.nn.log_softmax(x @ params["w"])
+        label = jnp.maximum(batch["label"], 0)
+        return -jnp.mean(jnp.take_along_axis(logp, label[:, None], 1)), {}
+
+    def sgd(grads, opt, params):
+        return jax.tree_util.tree_map(lambda p, g: p - 0.1 * g, params, grads), opt, {}
+
+    on_chip = lambda t: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)
+    params = {"w": jax.ShapeDtypeStruct((L, 8), jnp.float32)}
+    carry = on_chip(jax.eval_shape(
+        lambda p: init_carry(p, None, item_s, rcfg, label_field="label"), params))
+    batch = on_chip({n: jax.ShapeDtypeStruct((b,) + s.shape, s.dtype)
+                     for n, s in item_s.items()})
+    key = on_chip(jax.ShapeDtypeStruct((2,), jnp.uint32))
+    step = make_cl_step(loss, sgd, rcfg, strategy="rehearsal", exchange="local",
+                        label_field="label", task_field="task", sanitize=False)
+    compiled = step.lower(carry, batch, key).compile()
+
+    shape = re.escape(f"f32[{k},{slots},224,224,3]")
+    copies = re.findall(rf"= {shape}\{{[^}}]*\}}\S* copy\(", compiled.as_text())
+    assert copies == []
+    data_in = compiled.input_formats[0][0].buffer.data["images"]
+    assert data_in.layout.major_to_minor[-1] == 1  # the slot axis innermost
+    assert compiled.output_formats[0].buffer.data["images"] == data_in
