@@ -11,18 +11,22 @@ the control (the reference in float8 operands in the program's place) and the
 reference with a planted fault in the program's place (``half_batch``: half
 the rows left out, the mean taken over the rest; ``frozen``: state returned
 unchanged; ``no_replay``: the replay rows left out, in cells that rehearse),
-each compared with the float32 reference as a run compares the program.
-``--variants`` picks among these and ``bf16_witness``, the reference with its
-operands and their gradients rounded to bfloat16: how far rounding alone
-moves the numbers. One JSON line per reading, then a summary line: the
-largest reading of the program and of the witness (the lower readings) and
-the smallest of each control or fault (the upper readings) of every number.
+each compared with the float32 reference as a run compares the program;
+and, in cells of several workers, ``exchange_local``: a run of the program
+built with the exchange between chips left out (a local sample), checked
+against the reference's global sample. ``--variants`` picks among these and
+``bf16_witness``, the reference with its operands and their gradients
+rounded to bfloat16: how far rounding alone moves the numbers. One JSON line
+per reading, then a summary line: the largest reading of the program and of
+the witness (the lower readings) and the smallest of each control or fault
+(the upper readings) of every number.
 """
 import time
 
 T_START = time.time()
 
 import argparse  # noqa: E402
+import contextlib  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
@@ -34,6 +38,22 @@ VARIANTS = {"control": {"qdt": "float8_e4m3fn"}, "half_batch": {"fault": "half_b
             "frozen": {"fault": "frozen"}, "no_replay": {"fault": "no_replay"},
             "bf16_witness": {"qdt": "bfloat16"}}
 LOWER = ("program", "bf16_witness")
+EXCHANGE_LOCAL = "exchange_local"
+
+
+@contextlib.contextmanager
+def exchange_left_out():
+    """Within it, the mesh step is built with a local sample: each worker
+    draws its representatives from its own buffer, no exchange between
+    chips."""
+    from repro.launch import steps
+
+    orig = steps.build_train_step
+    steps.build_train_step = lambda *a, **k: orig(*a, **dict(k, exchange="local"))
+    try:
+        yield
+    finally:
+        steps.build_train_step = orig
 
 
 def main(argv=None):
@@ -42,7 +62,8 @@ def main(argv=None):
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--control-seeds", default="")
     ap.add_argument("--seconds", type=float, default=2.0)
-    ap.add_argument("--variants", default="control,half_batch,frozen,no_replay")
+    ap.add_argument("--variants",
+                    default="control,half_batch,frozen,no_replay,exchange_local")
     args = ap.parse_args(argv)
 
     import jax
@@ -74,6 +95,17 @@ def main(argv=None):
         spec, ref = cap["spec"], cap["ref"]
         for kind in args.variants.split(","):
             if kind == "no_replay" and not cap["layout"]["rehearse"]:
+                continue
+            if kind == EXCHANGE_LOCAL:
+                if cap["layout"]["group"] is None or cap["layout"]["n_workers"] < 2:
+                    continue
+                fault = {}
+                with exchange_left_out():
+                    res = runner.run(args.workload, seed, args.seconds, False,
+                                     root=root, t_start=time.time(), capture=fault)
+                print(json.dumps({"seed": seed, "kind": kind, "correct": res["correct"],
+                                  "numbers": fault["numbers"]}), flush=True)
+                readings.setdefault(kind, []).append(fault["numbers"])
                 continue
             out = reference.replay(spec["config"], spec["traffic"], cap["family"],
                                    cap["layout"], seed, spec["traffic"]["checked_steps"],

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from chipbench import generate
+from chipbench.entries import numeric_leaves
 
 
 class Entry:
@@ -112,7 +113,12 @@ class Entry:
     def step(self, batch, g):
         kstep = jax.random.fold_in(self.key0, g)
         self.carry, metrics = self.step_fn(self.carry, batch, kstep)
+        self.metrics = metrics
         return metrics["loss"]
+
+    def counters(self):
+        """The numeric outputs of the last step's metrics, by name."""
+        return numeric_leaves(self.metrics)
 
     def params(self):
         return self.carry.params

@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from chipbench import generate
+from chipbench.entries import numeric_leaves
 
 
 class Entry:
@@ -115,7 +116,12 @@ class Entry:
             *state, metrics = self.built.fn(*self.state, batch, self.issue_key)
             self.state = state
             self.issue_key = kstep
+        self.metrics = metrics
         return metrics["loss"]
+
+    def counters(self):
+        """The numeric outputs of the last step's metrics, by name."""
+        return numeric_leaves(self.metrics)
 
     def params(self):
         return self.state[0]

@@ -9,14 +9,19 @@ thread (``data.pipeline.Prefetcher``), beside the reduction of ``trace.py``.
   execution (the ``XLA Modules`` line) only. Nested events (a
   ``while`` around its body) are split so that each instant counts once, for
   the innermost event: the scopes and ``unscoped`` add up to the busy time.
+- Device time by collective: the same join gives each event its HLO opcode;
+  the cross-chip collectives (``COLLECTIVES``, with their ``-start`` and
+  ``-done`` forms) are summed under the collective's name. A TPU names an
+  instruction after its ``op_name``, not its opcode, so the name alone does
+  not tell a collective.
 - Idle gaps by program span: the gaps of ``trace.py``'s ``idle_gaps``,
   labelled by the program span that covers most of each, on any host thread.
 - Input counters: ``Prefetcher.counters()`` read before and after a stretch.
 - ``require_scopes``: a compiled step's text must carry its stages' scopes.
   JAX's persistent cache leaves metadata out of its key, so a step compiled
   without scopes (by an older program) is served with its old text, and
-  every op would read ``unscoped``; ``trace_program.py`` puts metadata in the
-  key and refuses such a text.
+  every op would read ``unscoped``; ``runner.enable_cache`` puts metadata in
+  the key, and ``trace_program.py`` refuses such a text.
 
 The reductions do not raise for want of what they read: a trace without
 scopes or input spans gives ``None`` or empty readings.
@@ -36,8 +41,13 @@ from chipbench import trace as trace_mod
 PROGRAM_SPANS = ("input.fetch", "input.convert", "input.wait", "input.place")
 SAMPLE_SCOPES = ("buffer_sample", "exchange", "augment")
 UNSCOPED = "unscoped"
+COLLECTIVES = ("all-reduce", "all-to-all", "all-gather", "reduce-scatter",
+               "collective-permute")
 
 _INSTR = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*?op_name="([^"]*)"')
+# the opcode is the first word after the shape that opens an operand list; a
+# shape's layout ``{0:T(8,128)}`` holds parentheses, but none after a space
+_OPCODE = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s.*?\s([a-z][\w\-]*)\(')
 _MODULE = re.compile(r"^HloModule\s+([\w.\-]+)")
 
 
@@ -56,6 +66,30 @@ def hlo_op_names(hlo_text: Optional[str]):
         if m:
             names[m.group(1)] = m.group(2)
     return module, names
+
+
+def hlo_collectives(hlo_text: Optional[str]) -> Dict[str, str]:
+    """{instruction: collective} of a compiled module's text, for the
+    instructions whose opcode is one of ``COLLECTIVES`` or its ``-start`` or
+    ``-done`` form."""
+    out = {}
+    for line in (hlo_text or "").splitlines():
+        m = _OPCODE.match(line)
+        if m:
+            op = re.sub(r"-(start|done)$", "", m.group(2))
+            if op in COLLECTIVES:
+                out[m.group(1)] = op
+    return out
+
+
+def step_hlo(ent, batch, key) -> str:
+    """The compiled text of the entry's step (its program as it runs): a
+    read of the compile cache where the step has run in this checkout."""
+    if hasattr(ent, "step_fn"):
+        lowered = ent.step_fn.lower(ent.carry, batch, key)
+    else:
+        lowered = ent.built.fn.lower(*ent.state, batch, key)
+    return lowered.compile().as_text()
 
 
 def _instruction(name: str) -> str:
@@ -135,6 +169,15 @@ def op_scopes(ops, hlo_text: Optional[str] = None) -> List[str]:
     return out
 
 
+def op_collectives(ops, hlo_text: Optional[str] = None) -> List[Optional[str]]:
+    """The collective each op is (None for the others), by its instruction's
+    opcode in the step's text."""
+    module, _ = hlo_op_names(hlo_text)
+    coll = hlo_collectives(hlo_text)
+    return [coll.get(instr) if op_module in (None, module) else None
+            for _, _, instr, op_module, _ in ops]
+
+
 def exclusive_times(intervals, keys, lo: float, hi: float) -> Dict[str, float]:
     """Seconds per key over [lo, hi], each instant given to the innermost
     (latest started) interval open at it: the values add up to the length of
@@ -176,7 +219,9 @@ def _gaps(ops, lo, hi):
 def reduce(devices, host, steps: int, hlo_text: Optional[str] = None, top: int = 10):
     """``trace.py``'s reduction of the same trace, plus ``device_scopes``
     ([[scope, ms per step]], mean over devices), ``scopes_busy_s`` (their
-    sum, in seconds), ``idle_gaps_program`` ([[program span, seconds]]),
+    sum, in seconds), ``device_collectives`` ([[collective, ms per step]],
+    mean over devices, each instant given to the innermost op as for the
+    scopes), ``idle_gaps_program`` ([[program span, seconds]]),
     ``device_ops_scoped`` (``device_ops`` with each op's scope) and
     ``program_span_s`` (seconds in each program span, all threads). None
     where ``trace.py`` gives None."""
@@ -190,12 +235,16 @@ def reduce(devices, host, steps: int, hlo_text: Optional[str] = None, top: int =
     lo = min(a for _, a, _ in bench)
     hi = max(b for _, _, b in bench)
     n = len(devices)
-    per_scope, op_scope = defaultdict(float), {}
+    per_scope, per_coll, op_scope = defaultdict(float), defaultdict(float), {}
     for ops in devices.values():
-        keys = op_scopes(ops, hlo_text)
-        for k, v in exclusive_times([op[:2] for op in ops], keys, lo, hi).items():
-            per_scope[k] += v / n
-        op_scope.update((op[4], k) for op, k in zip(ops, keys))
+        scopes = op_scopes(ops, hlo_text)
+        keys = list(zip(scopes, op_collectives(ops, hlo_text)))
+        for (scope, coll), v in exclusive_times([op[:2] for op in ops], keys,
+                                                lo, hi).items():
+            per_scope[scope] += v / n
+            if coll is not None:
+                per_coll[coll] += v / n
+        op_scope.update((op[4], k) for op, k in zip(ops, scopes))
     first = sorted(devices)[0]
     labelled = []
     for a, b in _gaps(devices[first], lo, hi):
@@ -212,6 +261,8 @@ def reduce(devices, host, steps: int, hlo_text: Optional[str] = None, top: int =
         device_scopes=sorted(([k, 1000.0 * v / steps] for k, v in per_scope.items()),
                              key=lambda x: -x[1]),
         scopes_busy_s=sum(per_scope.values()),
+        device_collectives=sorted(([k, 1000.0 * v / steps] for k, v in per_coll.items()),
+                                  key=lambda x: -x[1]),
         idle_gaps_program=labelled[:top],
         device_ops_scoped=[[name, op_scope[name], v] for name, v in base["device_ops"]],
         program_span_s={name: sum(e - s for nm, s, e in program if nm == name) / 1e9

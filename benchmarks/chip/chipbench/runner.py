@@ -44,6 +44,12 @@ def load_module(path):
     return mod
 
 
+def cell_metrics(per_layer, workload):
+    """The per-layer metrics a cell reports: those whose ``workloads`` name
+    it, and those without such a list."""
+    return [m for m in per_layer if workload in m.get("workloads", [workload])]
+
+
 def find_cell(root, workload):
     """The cell's entry, configuration, traffic, limits and metric lists."""
     bench = load_json(os.path.join(root, "BENCHMARK.json"))
@@ -57,7 +63,7 @@ def find_cell(root, workload):
         "traffic": load_json(os.path.join(HERE, "traffic", cell["traffic"] + ".json")),
         "limits": load_json(os.path.join(HERE, "limits", workload + ".json")),
         "end_to_end": bench["end_to_end"],
-        "per_layer": bench["per_layer"],
+        "per_layer": cell_metrics(bench["per_layer"], workload),
     }
 
 
@@ -94,11 +100,14 @@ def check_chips(jax, chips, peaks):
 
 def enable_cache(jax, root):
     """JAX's persistent compilation cache: $JAX_COMPILATION_CACHE_DIR where set,
-    else ``<checkout>/.jax_cache`` (a fixed path, so entries are found again)."""
+    else ``<checkout>/.jax_cache`` (a fixed path, so entries are found again).
+    Its key holds the programs' metadata: without it, a step read from the
+    cache carries no ``op_name``, and a traced run finds no scope."""
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(root, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return path
 
 
@@ -148,7 +157,7 @@ def run(workload, seed, seconds, trace, *, root, t_start, require_chip=True,
 
     sys.path.insert(0, os.path.join(root, "src"))
     sys.path.insert(0, HERE)
-    from chipbench import compare, generate, reference, stats
+    from chipbench import compare, generate, program_trace, reference, stats
 
     spec = spec or find_cell(root, workload)
     cell, cfg, tr = spec["cell"], spec["config"], spec["traffic"]
@@ -223,14 +232,21 @@ def run(workload, seed, seconds, trace, *, root, t_start, require_chip=True,
             compiles0 = clock.count
             setup_s = time.time() - t_start
             setup_compile_s = clock.secs
-            trace_out = None
             if trace:
                 trace_s = min(tr["trace_seconds"], seconds / 3)
+                c0 = pf.counters()
                 start, comps, spans, g = drive(ent, pf, g, seconds - trace_s)
-                trace_out = _traced(jax, ent, pf, g, trace_s)
+                c1 = pf.counters()
+                traced, t_steps, g = _traced(jax, ent, pf, g, trace_s)
             else:
                 start, comps, spans, g = drive(ent, pf, g, seconds)
             compiles_in_window = clock.count - compiles0
+            if trace:
+                # off the clock: after the window, before the state is freed
+                _, batch = pf.next()
+                hlo = program_trace.step_hlo(ent, batch, jax.random.fold_in(ent.key0, g))
+                counters = {"input": {k: c1[k] - c0[k] for k in c1},
+                            "step": ent.counters() if hasattr(ent, "counters") else {}}
         finally:
             pf.stop()
         mem = [d.memory_stats() or {} for d in devices]
@@ -273,9 +289,14 @@ def run(workload, seed, seconds, trace, *, root, t_start, require_chip=True,
               "count": len(devices), "memory_peak_bytes": memory_peak}
     result = {"correct": bool(ok), "attempted": steps, "failed": 0}
     if trace:
+        trace_out = program_trace.reduce(*traced, t_steps, hlo)
         record = {"window_s": window_s, "steps": steps, "spans": spans,
                   "n_chips": n, "peaks": peak, "trace": trace_out,
-                  "flops_per_step": rows_per_step * family.train_flops_per_row(cfg, tr)}
+                  "flops_per_step": rows_per_step * family.train_flops_per_row(cfg, tr),
+                  "scopes": dict(trace_out["device_scopes"]) if trace_out else {},
+                  "collectives": (dict(trace_out["device_collectives"])
+                                  if trace_out else {}),
+                  "counters": counters}
         metrics = {}
         for m in spec["per_layer"]:
             v = load_module(os.path.join(HERE, "metrics", m["name"] + ".py")).read(record)
@@ -284,7 +305,8 @@ def run(workload, seed, seconds, trace, *, root, t_start, require_chip=True,
         if trace_out is not None:
             device.update(busy_s=trace_out["busy_s"], window_s=trace_out["window_s"])
             result["breakdown"] = {"device_ops": trace_out["device_ops"],
-                                   "idle_gaps": trace_out["idle_gaps"]}
+                                   "idle_gaps": trace_out["idle_gaps"],
+                                   "device_scopes": trace_out["device_scopes"][:10]}
     else:
         metrics = {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
                    for m in spec["end_to_end"]}
@@ -306,17 +328,19 @@ def run(workload, seed, seconds, trace, *, root, t_start, require_chip=True,
 
 
 def _traced(jax, ent, pf, g, seconds):
-    """A traced stretch of the window; returns the trace's reduction."""
-    from chipbench import trace as trace_mod
+    """A traced stretch of the window; returns ((device ops, host spans) as
+    ``program_trace.read_xplane`` reads the trace, the steps completed in
+    the stretch, the next g)."""
+    from chipbench import program_trace
 
     out = tempfile.mkdtemp(prefix="chipbench-trace-")
     try:
         jax.profiler.start_trace(out)
         try:
-            drive(ent, pf, g, seconds, annotate=jax.profiler.TraceAnnotation)
+            _, comps, _, g = drive(ent, pf, g, seconds,
+                                   annotate=jax.profiler.TraceAnnotation)
         finally:
             jax.profiler.stop_trace()
-        devices, host = trace_mod.from_xplane(out)
-        return trace_mod.reduce_events(devices, host)
+        return program_trace.read_xplane(out), len(comps), g
     finally:
         shutil.rmtree(out, ignore_errors=True)

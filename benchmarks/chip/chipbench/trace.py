@@ -1,5 +1,6 @@
-"""Reduction of a profiler trace (``.xplane.pb``) to device busy time, idle
-gaps and the operations that took most time.
+"""Reduction of a profiler trace to device busy time, idle gaps and the
+operations that took most time (``program_trace.read_xplane`` reads the
+trace file).
 
 Device operations are the events of the ``XLA Ops`` line of each ``/device:``
 plane. Host spans are the events named ``fetch``, ``dispatch`` and ``wait``
@@ -9,8 +10,6 @@ host span's start to the last one's end.
 """
 from __future__ import annotations
 
-import glob
-import os
 from collections import defaultdict
 from typing import Dict, List, Tuple
 
@@ -19,35 +18,6 @@ NAME_CHARS = 160  # an op's name is its HLO instruction, cut to this length
 OPS_LINE = "XLA Ops"
 
 Event = Tuple[str, float, float]  # (name, start_ns, end_ns)
-
-
-def from_xplane(path: str):
-    """(device events {plane: [Event]}, host spans [Event]) of a trace file,
-    or of the newest ``*.xplane.pb`` under a directory."""
-    from jax.profiler import ProfileData
-
-    if os.path.isdir(path):
-        found = sorted(glob.glob(os.path.join(path, "**", "*.xplane.pb"),
-                                 recursive=True), key=os.path.getmtime)
-        if not found:
-            raise FileNotFoundError(f"no .xplane.pb under {path}")
-        path = found[-1]
-    pd = ProfileData.from_file(path)
-    devices, host = {}, []
-    for plane in pd.planes:
-        if plane.name.startswith("/device:"):
-            evs = []
-            for line in plane.lines:
-                if line.name == OPS_LINE:
-                    evs += [(e.name[:NAME_CHARS], e.start_ns,
-                             e.start_ns + e.duration_ns) for e in line.events]
-            if evs:
-                devices[plane.name] = evs
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                host += [(e.name, e.start_ns, e.start_ns + e.duration_ns)
-                         for e in line.events if e.name in HOST_SPANS]
-    return devices, host
 
 
 def _union(intervals: List[Tuple[float, float]], lo: float, hi: float):

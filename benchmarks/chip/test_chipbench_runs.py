@@ -64,7 +64,7 @@ def _spec(name):
     limits = json.load(open(os.path.join(HERE, "limits", cell + ".json")))
     return {"cell": {"name": name, "chips": 1}, "config": cfg, "traffic": tr,
             "limits": limits, "end_to_end": bench["end_to_end"],
-            "per_layer": bench["per_layer"]}
+            "per_layer": runner.cell_metrics(bench["per_layer"], cell)}
 
 
 def _run(name, hook=None, trace=False, capture=None):
@@ -85,5 +85,7 @@ def test_sound_run_is_correct(name):
 def test_traced_run_reports_per_layer_metrics():
     res = _run("cnn", trace=True)
     assert res["correct"], res["checks"]
-    # no peak and no device plane on the CPU: those two readers stay silent
-    assert set(res["metrics"]) == {"input_wait_pct", "dispatch_ms"}
+    # no peak and no device plane on the CPU: the readers of the peak and of
+    # the device trace stay silent; the input counters are read
+    assert set(res["metrics"]) == {"input_wait_pct", "dispatch_ms",
+                                   "input_not_ready_pct", "input_convert_ms"}

@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """One cell's window read by what the program marks itself: device time per
-named scope of the step, idle gaps by the input thread's spans, and the
-``Prefetcher`` counters of an untraced and a traced stretch.
+named scope of the step and per collective, idle gaps by the input thread's
+spans, and the ``Prefetcher`` counters of an untraced and a traced stretch.
 
     python3 benchmarks/chip/trace_program.py --workload resnet50_async_flat \\
         --seed 12345 --seconds 20 --out chiprun_out/scopes
@@ -12,8 +12,8 @@ stream and loop (``runner.drive``). The window runs ``--seconds`` untraced,
 then the traffic's ``trace_seconds`` under the profiler. The last line of
 standard output is the result as JSON; ``--out`` also keeps the step's HLO
 text and the trace file (gzip, up to 16 MiB), to reduce again without the
-chip. The compile cache is keyed with metadata here, so the step is compiled
-once more than ``run.py`` does; a step text without its scopes exits 1.
+chip. The compile cache is keyed with metadata (``runner.enable_cache``), as
+``run.py`` keys it; a step text without its scopes exits 1.
 """
 import time
 
@@ -32,15 +32,6 @@ sys.path.insert(0, HERE)
 
 SPAN_COST_CALLS = 20000
 KEEP_TRACE_BYTES = 16 << 20  # a larger compressed trace is not kept
-
-
-def step_hlo(ent, batch, key):
-    """The compiled text of the entry's step (its program as it runs)."""
-    if hasattr(ent, "step_fn"):
-        lowered = ent.step_fn.lower(ent.carry, batch, key)
-    else:
-        lowered = ent.built.fn.lower(*ent.state, batch, key)
-    return lowered.compile().as_text()
 
 
 def slow_steps(start, comps, spans, top=10, factor=1.5):
@@ -125,7 +116,7 @@ def measure(workload, seed, seconds, *, root, out=None, require_chip=True,
                 ent.step(batch, g).block_until_ready()
                 g += 1
             # the step's text, compiled outside the window (a cache read)
-            hlo = step_hlo(ent, batch, jax.random.fold_in(ent.key0, g))
+            hlo = program_trace.step_hlo(ent, batch, jax.random.fold_in(ent.key0, g))
             program_trace.require_scopes(hlo, rehearsal=tr["mode"] != "off")
             compiles0 = clock.count
             setup_s = time.time() - T_START
@@ -180,6 +171,7 @@ def measure(workload, seed, seconds, *, root, out=None, require_chip=True,
             "buffer_update_ms": program_trace.scope_ms(red, ("buffer_update",)),
             "buffer_sample_ms": program_trace.scope_ms(red, program_trace.SAMPLE_SCOPES),
             "unscoped_device_pct": program_trace.unscoped_pct(red)}
+        result["collectives"] = dict(red["device_collectives"])
         result["breakdown"] = {k: red[k] for k in (
             "window_s", "busy_s", "scopes_busy_s", "idle_pct", "device_scopes",
             "device_ops_scoped", "idle_gaps", "idle_gaps_program", "idle_by_span",
@@ -201,9 +193,6 @@ def main(argv=None):
 
     root = os.path.dirname(os.path.dirname(HERE))
     runner.enable_cache(jax, root)
-    # scopes are metadata: without this, a cached step compiled before them
-    # is served with its unscoped text
-    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     try:
         result = measure(args.workload, args.seed, args.seconds, root=root, out=args.out)
     except runner.NoChip as e:
